@@ -217,15 +217,19 @@ def derived_claims(rows) -> dict[str, float]:
 def run_spmd_rebuild() -> dict[str, float]:
     """Time the §13 spmd engine rebuild in a subprocess: it needs its own
     8-fake-device topology (XLA_FLAGS is per-process), so the measurement
-    cannot run in this interpreter.  Returns the claims dict printed by
+    cannot run in this interpreter.  The child is held to the host CPU —
+    fake devices exist only there — and :func:`main` starts it before this
+    process initialises any JAX backend, so on a TPU host the parent never
+    holds a chip while a JAX child runs.  Returns the claims dict printed by
     ``benchmarks/spmd_elastic.py``."""
     import json
     import subprocess
 
     script = os.path.join(os.path.dirname(__file__), "spmd_elastic.py")
+    env = {k: v for k, v in os.environ.items() if k != "XLA_FLAGS"}
     proc = subprocess.run(
         [sys.executable, script], capture_output=True, text=True, timeout=560,
-        env={k: v for k, v in os.environ.items() if k != "XLA_FLAGS"},
+        env={**env, "JAX_PLATFORMS": "cpu"},
     )
     if proc.returncode != 0:
         raise RuntimeError(
@@ -246,9 +250,10 @@ def _merge_into_bench_run(name: str, claims: dict) -> None:
 
 
 def main() -> int:
+    rebuild_claims = run_spmd_rebuild()  # first: its JAX child needs a JAX-free parent
     rows = run()
     claims = derived_claims(rows)
-    claims.update(run_spmd_rebuild())
+    claims.update(rebuild_claims)
     print("scheme,m,plan_build_ms,first_decodable_ms,decode_cold_us,decode_warm_us,n_groups")
     for r in rows:
         print(

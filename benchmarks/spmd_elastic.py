@@ -1,9 +1,10 @@
 """SPMD elastic-rebuild latency benchmark (DESIGN.md §13 gate).
 
-Standalone subprocess (needs its OWN device topology, so it must set
-XLA_FLAGS before jax imports — the parent gate runs it via
-``benchmarks/scaling.py``): builds a compressed-wire spmd engine on an
-(8, 1) mesh at m=8, warms the step, then times one full shrink
+Standalone subprocess on the host CPU (needs its OWN fake-device topology,
+so it must set XLA_FLAGS before jax imports — the parent gate runs it via
+``benchmarks/scaling.py`` with ``JAX_PLATFORMS=cpu``): builds a
+compressed-wire spmd engine on an (8, 1) mesh at m=8, warms the step,
+then times one full shrink
 (m=8→7) and one full grow (m=7→8) INCLUDING the post-transition
 gradient step — i.e. mesh re-derivation + shard_map re-jit + err-row
 carry + first step on the new program, the whole churn-to-first-step

@@ -16,9 +16,11 @@ Three implementations of the same math, used at different layers:
    safe, zero extra collectives vs naive DP.  (Beyond-paper optimization;
    agreement with (1) is property-tested.)
 
-3. ``faithful_spmd_step`` — the protocol under ``jax.shard_map``: manual over
-   the coding axes, auto over 'model' (TP).  Each worker flattens its
-   per-slot gradients into one (D,) buffer (``ravel_pytree``), encodes them
+3. ``faithful_spmd_step`` — the protocol under ``jax.shard_map``, manual over
+   every mesh axis (Mosaic kernels cannot be partitioned automatically, so
+   the Pallas encode/decode must run where no axis is left to GSPMD).
+   Each worker flattens its per-slot gradients into one (D,) buffer
+   (``wire_ravel``, lane-aligned — see there), encodes them
    in a single pass through the roofline-optimal ``coded_reduce`` Pallas
    kernel (``interpret=True`` off-TPU), optionally compresses the flat wire
    tensor (int8 + error feedback) exactly where the wire format would apply,
@@ -51,7 +53,6 @@ from typing import Any, Callable, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.flatten_util import ravel_pytree
 
 from repro.core.coding import CodingScheme
 from repro.core.decoding import Decoder
@@ -71,6 +72,8 @@ __all__ = [
     "protocol_reference",
     "fused_coded_value_and_grad",
     "faithful_spmd_step",
+    "wire_ravel",
+    "wire_unraveler",
     "remap_err_rows",
 ]
 
@@ -78,20 +81,58 @@ PyTree = Any
 LossFn = Callable[[PyTree, PyTree], jnp.ndarray]  # (params, slot_batch) -> scalar
 
 
-def _shard_map(fn, mesh, in_specs, out_specs, manual_axes: tuple[str, ...]):
-    """shard_map across jax versions: manual over ``manual_axes``, auto over
-    the rest ('model' stays GSPMD-handled either way)."""
-    if hasattr(jax, "shard_map"):  # jax >= 0.6
-        return jax.shard_map(
-            fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            axis_names=frozenset(manual_axes), check_vma=False,
-        )
-    from jax.experimental.shard_map import shard_map  # jax 0.4.x
+LANE = 128  # TPU vector lane width: the minor-dim tile of every HBM layout
 
-    # the `auto=` subgroup path trips an XLA CHECK on 0.4.x CPU, so go fully
-    # manual: non-coding axes see replicated blocks (duplicate compute over
-    # 'model' — acceptable for the protocol/benchmark path on old jax)
-    return shard_map(fn, mesh, in_specs=in_specs, out_specs=out_specs, check_rep=False)
+
+def _lane_pad(shape: tuple[int, ...]) -> int:
+    """Zero columns that round a ≥2-D leaf's minor dim up to a lane multiple."""
+    return (-shape[-1]) % LANE if len(shape) >= 2 else 0
+
+
+def wire_ravel(tree: PyTree) -> jnp.ndarray:
+    """Flatten a gradient pytree into the spmd wire's (D,) f32 buffer.
+
+    Leaf order is ``jax.tree.leaves`` order, as in ``ravel_pytree``, but
+    every ≥2-D leaf's minor dim is first zero-padded to a multiple of
+    :data:`LANE`.  On TPU a leaf whose minor dim is not a lane multiple
+    (d_model 960, kv width 320) is stored with padded tiles, and flattening
+    it straight into a concatenation makes XLA's TPU backend emit a
+    per-row relayout whose compile time grows with the row count — minutes
+    for one full-width model.  Padded, the relayout is tile-aligned.  The
+    pad columns carry zeros through the encode (so they never move the
+    int8 scale) and :func:`wire_unraveler` drops them again."""
+    parts = []
+    for x in jax.tree.leaves(tree):
+        x = x.astype(jnp.float32)
+        pad = _lane_pad(x.shape)
+        if pad:
+            x = jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, pad)])
+        parts.append(x.reshape(-1))
+    return jnp.concatenate(parts)
+
+
+def wire_unraveler(like: PyTree) -> tuple[Callable[[jnp.ndarray], PyTree], int]:
+    """Inverse of :func:`wire_ravel` for trees shaped like ``like``, and the
+    wire width D it expects."""
+    leaves, treedef = jax.tree.flatten(like)
+    layout, off = [], 0
+    for x in leaves:
+        shape = tuple(x.shape)
+        padded = shape[:-1] + (shape[-1] + _lane_pad(shape),) if shape else shape
+        n = int(np.prod(padded))
+        layout.append((off, n, padded, shape, x.dtype))
+        off += n
+
+    def unravel(flat: jnp.ndarray) -> PyTree:
+        out = []
+        for start, n, padded, shape, dtype in layout:
+            x = flat[start:start + n].reshape(padded)
+            if padded != shape:
+                x = x[..., : shape[-1]]
+            out.append(x.astype(dtype))
+        return jax.tree.unflatten(treedef, out)
+
+    return unravel, off
 
 
 def remap_err_rows(err: jnp.ndarray, old_of_new) -> jnp.ndarray:
@@ -346,7 +387,7 @@ def fused_coded_value_and_grad(loss_fn: LossFn) -> Callable[[PyTree, PyTree, jnp
 
 
 # ---------------------------------------------------------------------------
-# 3. faithful SPMD protocol (shard_map, manual over coding axes)
+# 3. faithful SPMD protocol (shard_map, manual over every mesh axis)
 # ---------------------------------------------------------------------------
 
 
@@ -375,12 +416,13 @@ def faithful_spmd_step(
     worker keeps its own quantization residual on the wire tensor).
 
     Data path per worker: the per-slot gradient pytrees are flattened into a
-    (n_max, D) stack (``ravel_pytree``, fixed leaf order), the encode
+    (n_max, D) stack (:func:`wire_ravel`, fixed leaf order), the encode
     g̃_w = Σ_s coeff[w,s]·g_s is ONE single-pass ``coded_reduce`` Pallas call
-    (``interpret=True`` off-TPU — auto-detected when ``interpret`` is None),
+    (``interpret=True`` off-TPU — taken from the mesh's platform when
+    ``interpret`` is None),
     and the master decode g = Σ_w a_w·g̃_w is ONE psum over the flat (D,)
     buffer instead of a per-leaf tree walk.  Callers unravel the result once
-    with the params structure's ``ravel_pytree`` inverse.
+    with :func:`wire_unraveler` of the params structure.
 
     ``wire_kernel`` (``compress`` only) switches the quantize stage to the
     fused Pallas wire kernels (DESIGN.md §12): encode+quantize+error-feedback
@@ -392,11 +434,13 @@ def faithful_spmd_step(
     f32 reduction order.  None → :func:`repro.kernels.autotune.
     wire_kernel_default` (True only where the fused kernel measured faster).
 
-    Manual only over ``coding_axes`` — the 'model' axis stays auto so TP
-    sharding inside loss_fn is still handled by GSPMD.
+    Manual over EVERY mesh axis: Mosaic refuses to partition a Pallas call
+    automatically, so no axis may be left to GSPMD around the kernels.
+    Params enter replicated; on a mesh with a 'model' axis of size > 1 each
+    model-axis shard computes its worker's gradient whole.
     """
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = mesh.devices.flat[0].platform != "tpu"
     if wire_kernel is None:
         from repro.kernels.autotune import wire_kernel_default
 
@@ -410,7 +454,7 @@ def faithful_spmd_step(
 
         def slot_grad(carry, slot):
             g = jax.grad(loss_fn)(params, slot)
-            return carry, ravel_pytree(g)[0].astype(jnp.float32)
+            return carry, wire_ravel(g)
 
         _, gstack = jax.lax.scan(slot_grad, None, sb)  # (n_max, D)
         if compress and wire_kernel:
@@ -438,7 +482,7 @@ def faithful_spmd_step(
 
     dp = jax.sharding.PartitionSpec(coding_axes)
     rep = jax.sharding.PartitionSpec()
-    return _shard_map(
-        worker_fn, mesh, in_specs=(rep, dp, dp, dp, dp), out_specs=(rep, dp),
-        manual_axes=coding_axes,
+    return jax.shard_map(
+        worker_fn, mesh=mesh, in_specs=(rep, dp, dp, dp, dp), out_specs=(rep, dp),
+        check_vma=False,
     )

@@ -74,6 +74,10 @@ def _chunk_contrib(w, g, *, rows_live: int | None = None):
     wire kernels' bit-equality contract at rare shapes.  A dot's
     accumulation order is fixed by the dot emitter's shape-determined
     tiling, so identical (PC, T) gives identical bits in every kernel.
+    ``precision=HIGHEST`` pins a full-f32 contraction in Mosaic (its default
+    may round the operands to bf16, which would cost the gradient and the
+    int8 decode's ``a_w·scale_w`` weights mantissa bits); the interpreter
+    computes f32 either way.
     """
     wf = w.astype(jnp.float32)  # (PC, 1)
     gf = g.astype(jnp.float32)  # (PC, T)
@@ -86,6 +90,7 @@ def _chunk_contrib(w, g, *, rows_live: int | None = None):
     return jax.lax.dot_general(
         wf, gf,
         dimension_numbers=(((0,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32,
     )  # (1, T)
 
@@ -135,7 +140,7 @@ def _tpu_call_hints(n_d: int, flops: int, nbytes: int, interpret: bool) -> dict:
     from jax.experimental.pallas import tpu as pltpu
 
     return {
-        "compiler_params": pltpu.TPUCompilerParams(
+        "compiler_params": pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         "cost_estimate": pl.CostEstimate(

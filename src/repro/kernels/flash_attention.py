@@ -126,7 +126,7 @@ def flash_attention_pallas(
         hints = {
             # head and q-block axes are independent; the kv axis carries the
             # online-softmax running state (m/l/acc scratch)
-            "compiler_params": pltpu.TPUCompilerParams(
+            "compiler_params": pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel", "arbitrary"),
             ),
             "cost_estimate": pl.CostEstimate(

@@ -149,7 +149,7 @@ def coded_encode_int8_pallas(
     hints = {}
     if not interpret:
         hints = {
-            "compiler_params": pltpu.TPUCompilerParams(
+            "compiler_params": pltpu.CompilerParams(
                 # the phase axis carries the global max; every axis sequential
                 dimension_semantics=("arbitrary", "arbitrary", "arbitrary"),
             ),

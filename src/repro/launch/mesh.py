@@ -3,23 +3,14 @@
 A function, not a module-level constant, so importing this module never
 touches jax device state (device count is locked at first backend init —
 dryrun.py must set XLA_FLAGS before this runs).
-
-``AxisType`` (explicit sharding-in-types) only exists on newer jax; on
-older releases (e.g. 0.4.x) every mesh axis is implicitly Auto, so the
-compat constructor simply omits the argument.
 """
 
 from __future__ import annotations
 
 import jax
-
-try:  # jax >= 0.5: explicit Auto/Explicit/Manual axis types
-    from jax.sharding import AxisType
-except ImportError:  # jax 0.4.x: all axes are Auto, no arg to pass
-    AxisType = None
+from jax.sharding import AxisType
 
 __all__ = [
-    "AxisType",
     "make_auto_mesh",
     "make_production_mesh",
     "data_axes",
@@ -31,9 +22,7 @@ __all__ = [
 
 
 def make_auto_mesh(shape, axes) -> jax.sharding.Mesh:
-    """``jax.make_mesh`` with all-Auto axis types on any jax version."""
-    if AxisType is None:
-        return jax.make_mesh(shape, axes)
+    """``jax.make_mesh`` with every axis Auto (GSPMD-partitioned)."""
     return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 

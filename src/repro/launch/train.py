@@ -9,9 +9,11 @@ Examples:
   PYTHONPATH=src python -m repro.launch.train --arch smollm-360m --reduced \\
       --steps 80 --m 6 --ckpt-dir /tmp/ck --resume
 
-On a real TPU deployment this process would run per-host under the usual
-multi-controller launcher; the coded-aggregation path is pure pjit and needs
-no code changes — only the mesh axes in CodingConfig.coding_axes.
+The first line printed names the devices the run found (platform, kind,
+count); the last is a JSON summary, which ``main`` also returns.  The spmd
+backend's Pallas kernels run compiled on a TPU and in interpret mode on any
+other platform (tests and CPU runs).  ``chip_smoke.py`` at the repo root
+drives this entry point on one TPU chip, and the spmd backend on four.
 """
 
 from __future__ import annotations
@@ -28,12 +30,12 @@ from repro.checkpoint import AsyncCheckpointer, latest_step, restore_checkpoint
 from repro.configs import CodingConfig, TrainConfig, get_config
 from repro.core.registry import scheme_names
 from repro.core.straggler import (
-    FaultModel,
     FixedDelayStragglers,
     NoStragglers,
     TransientStragglers,
 )
 from repro.data.pipeline import SyntheticData
+from repro.launch.runtime import device_info, enable_compilation_cache
 from repro.models.lm import build_model
 from repro.obs.trace import Tracer
 from repro.optim.adam import adamw_init
@@ -115,6 +117,8 @@ def main(argv=None):
                          "faster than the unfused composition on this host "
                          "(DESIGN.md §12)")
     args = ap.parse_args(argv)
+    print(json.dumps({"device": device_info()}), flush=True)
+    enable_compilation_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:
@@ -175,11 +179,17 @@ def main(argv=None):
             print(f"resumed from step {last} (saved with m={meta.get('m')}, now m={args.m})")
 
     t0 = time.time()
-    totals = {"sim": 0.0}
+    totals = {"sim": 0.0, "last": time.perf_counter()}
+    losses, step_wall_s = [], []
 
     def on_step(step, st, metrics):
         # runs inside the double-buffered trainer loop (batch t+1 is already
-        # uploading while this fires — DESIGN.md §6)
+        # uploading while this fires — DESIGN.md §6); the engine has read
+        # the step's metrics back, so the device work of the step is done
+        now = time.perf_counter()
+        step_wall_s.append(now - totals["last"])
+        totals["last"] = now
+        losses.append(metrics["loss"])
         totals["sim"] += (
             metrics["sim_iter_time"] if np.isfinite(metrics["sim_iter_time"]) else 0.0
         )
@@ -209,17 +219,22 @@ def main(argv=None):
             print(f"event log: {args.log_jsonl} ({n} lines) — analyse with "
                   f"python -m repro.launch.obs_report {args.log_jsonl}")
     # metrics is {} when the loop ran zero steps (e.g. --resume at --steps)
-    print(json.dumps({
+    summary = {
+        "n_params": model.param_count(state.params),
         "final_loss": metrics.get("loss"), "wall_s": time.time() - t0,
         "sim_time_total_s": sim_total, "scheme": args.scheme, "m": args.m,
         "deadline_mode": args.deadline_mode,
         "exact_fraction": metrics.get("exact_fraction"),
         "steps_run": max(args.steps - start, 0),
+        "losses": losses,
+        "step_wall_s": step_wall_s,
         **(
             {"resilience": trainer.supervisor.summary(), "m_final": trainer.m}
             if trainer.supervisor is not None else {}
         ),
-    }))
+    }
+    print(json.dumps(summary))
+    return summary
 
 
 if __name__ == "__main__":

@@ -44,7 +44,6 @@ from typing import Any, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.flatten_util import ravel_pytree
 
 from repro.checkpoint.placement import place_rows
 from repro.configs.base import TrainConfig
@@ -56,6 +55,7 @@ from repro.core.aggregator import (
     slot_weights,
     slot_weights_device,
     support_slot_mask_device,
+    wire_unraveler,
 )
 from repro.core.codec import Codec
 from repro.core.decoding import DecodeOutcome
@@ -476,6 +476,10 @@ class StepEngine:
         m_before = self._spmd_m if self._spmd_m is not None else m
         mesh_rebuilt, program_rebuilt = self._ensure_spmd_program()
         width = self._err_width
+        # dim 0 split over the coding axes: one row on each worker's device
+        err_sharding = jax.sharding.NamedSharding(
+            self.mesh, jax.sharding.PartitionSpec(self.coding_axes)
+        )
         carried = 0
         if (
             self._err is not None
@@ -491,18 +495,15 @@ class StepEngine:
         ):
             carried = m  # pure rebalance: identities unchanged, all rows carry
         else:
-            self._err = jnp.zeros((m, width), jnp.float32)
-        if mesh_rebuilt and self.mesh is not None:
+            # placed at birth: an unplaced (m, D) buffer would sit whole on
+            # the first device
+            self._err = jnp.zeros((m, width), jnp.float32, device=err_sharding)
+        if mesh_rebuilt:
             # the carried rows are still committed to the OLD device set;
             # re-place them onto the new mesh (device-to-device gather —
             # the rows never bounce through the host) under the program's
-            # err spec: dim 0 split over the coding axes
-            self._err = jax.device_put(
-                self._err,
-                jax.sharding.NamedSharding(
-                    self.mesh, jax.sharding.PartitionSpec(self.coding_axes)
-                ),
-            )
+            # err spec
+            self._err = jax.device_put(self._err, err_sharding)
             self._state_mesh_stale = True
         self._row_map = None
         self._err_version = self.codec.version
@@ -564,8 +565,8 @@ class StepEngine:
         traced = tr.enabled
         t0 = tr.clock() if traced else 0.0
         if self._unravel is None:
-            flat0, self._unravel = ravel_pytree(params)
-            self._err_width = int(flat0.size) if self.compress else 1
+            self._unravel, width = wire_unraveler(params)
+            self._err_width = width if self.compress else 1
         if self._err is None or self._err_version != self.codec.version:
             # first call, or a membership change / rebalance re-encoded the
             # plan: run the elastic rebuild — mesh + program re-derived at

@@ -17,7 +17,7 @@ import numpy as np  # noqa: E402
 from jax.sharding import NamedSharding  # noqa: E402
 from jax.sharding import PartitionSpec as P  # noqa: E402
 
-from repro.launch.mesh import make_auto_mesh  # noqa: E402 (AxisType compat)
+from repro.launch.mesh import make_auto_mesh  # noqa: E402
 
 
 def _toy():
@@ -70,15 +70,14 @@ def check_faithful_spmd():
     """Flat wire format (DESIGN.md §6): per-worker Pallas encode of the
     ravelled gradient stack, ONE psum decode over the (D,) buffer —
     matches the per-partition ground truth, compressed path stays close."""
-    from jax.flatten_util import ravel_pytree
-
     from repro.core import Decoder, build_heter_aware
-    from repro.core.aggregator import faithful_spmd_step, make_plan, pack_coded_batch
+    from repro.core.aggregator import (
+        faithful_spmd_step, make_plan, pack_coded_batch, wire_unraveler,
+    )
 
     mesh = make_auto_mesh((4, 2), ("data", "model"))
     loss_fn, params, r = _toy()
-    flat0, unravel = ravel_pytree(params)
-    D = int(flat0.size)
+    unravel, D = wire_unraveler(params)
     params = jax.device_put(
         params,
         {"w1": NamedSharding(mesh, P(None, "model")), "w2": NamedSharding(mesh, P("model", None))},
@@ -608,6 +607,15 @@ def check_dryrun_small():
     print("dryrun small ok: flops/chip", rep.flops_per_chip, "bottleneck", rep.bottleneck)
 
 
+def check_chip_smoke_spmd():
+    """``chip_smoke.py --chips 4``'s phase (spmd vs fused gradients, compress
+    off and on, spread over four devices) at reduced size on fake devices."""
+    sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)), ".."))
+    import chip_smoke
+
+    chip_smoke.spmd_phase(reduced=True, seq_len=16)
+
+
 if __name__ == "__main__":
     {
         "faithful_spmd": check_faithful_spmd,
@@ -619,4 +627,5 @@ if __name__ == "__main__":
         "engine_spmd_elastic": check_engine_spmd_elastic,
         "spmd_trainer_resume": check_spmd_trainer_resume,
         "dryrun_small": check_dryrun_small,
+        "chip_smoke_spmd": check_chip_smoke_spmd,
     }[sys.argv[1]]()

@@ -143,3 +143,28 @@ def test_plan_padding_stable_shapes():
         w = slot_weights(plan, Decoder(sch).decode_vector(range(4)))
         _, grads = vg(params, pack_coded_batch(pb, plan), jnp.asarray(w))
         assert _trees_close(grads, gt)
+
+
+def test_wire_ravel_roundtrip_lane_padded():
+    """The spmd wire flattens leaves in tree order, zero-pads each ≥2-D
+    leaf's minor dim to 128 lanes, and its unraveler restores the tree."""
+    from repro.core.aggregator import wire_ravel, wire_unraveler
+
+    r = np.random.default_rng(3)
+    tree = {
+        "a": jnp.asarray(r.normal(size=(3, 130)), jnp.bfloat16),  # pads to 256
+        "b": jnp.asarray(r.normal(size=(2, 5, 128)), jnp.float32),  # aligned
+        "c": jnp.asarray(r.normal(size=(7,)), jnp.float32),  # 1-D: no pad
+        "d": jnp.asarray(1.5, jnp.float32),  # scalar
+    }
+    flat = wire_ravel(tree)
+    unravel, D = wire_unraveler(tree)
+    assert flat.dtype == jnp.float32 and flat.shape == (D,)
+    assert D == 3 * 256 + 2 * 5 * 128 + 7 + 1
+    a_rows = np.asarray(flat[: 3 * 256]).reshape(3, 256)
+    assert np.all(a_rows[:, 130:] == 0)  # pad lanes carry zeros
+    np.testing.assert_array_equal(a_rows[:, :130], np.asarray(tree["a"], np.float32))
+    back = unravel(flat)
+    for k in tree:
+        assert back[k].dtype == tree[k].dtype and back[k].shape == tree[k].shape
+        np.testing.assert_array_equal(np.asarray(back[k]), np.asarray(tree[k]))

@@ -44,3 +44,7 @@ def test_engine_spmd_backend_matches_reference_after_membership_change():
 
 def test_dryrun_lowering_small_mesh():
     _run("dryrun_small")
+
+
+def test_chip_smoke_four_chip_phase_on_fake_devices():
+    _run("chip_smoke_spmd")
