@@ -2,7 +2,7 @@
 # driver runs); PYTHONPATH plumbing lives in scripts/test.sh so it stops
 # being tribal knowledge.
 
-.PHONY: test test-fast test-tier2 test-membership churn-soak chaos-soak bench bench-smoke bench-scaling bench-serving bench-obs bench-resilience bench-kernels quickstart
+.PHONY: test test-fast test-tier2 test-membership churn-soak chaos-soak bench bench-smoke bench-scaling bench-serving bench-resilience bench-kernels quickstart
 
 test:
 	./scripts/test.sh
@@ -33,9 +33,6 @@ bench-scaling:  ## large-m control-plane gate: m in {20,64,256} x schemes; fails
 
 bench-serving:  ## coded-serving gate: decode micro + p99-TTFT >= 1.3x over wait-for-all at 30% stragglers
 	PYTHONPATH=src:. BENCH_FAST=1 python benchmarks/serving.py
-
-bench-obs:  ## observability overhead gate: tracing-on <= 1.05x tracing-off fused us/step
-	PYTHONPATH=src:. BENCH_FAST=1 python benchmarks/obs_overhead.py
 
 bench-resilience:  ## resilience gate: degraded time-to-target <= 1.5x fault-free under 1 crash + 1 hang
 	PYTHONPATH=src:. BENCH_FAST=1 python benchmarks/resilience.py

@@ -1,7 +1,7 @@
 """Shared benchmark-artifact plumbing.
 
 Every writer of ``results/BENCH_run.json`` — the full ``benchmarks/run.py``
-sweep and the standalone section benches (serving, scaling, obs_overhead) —
+sweep and the standalone section benches (serving, scaling) —
 goes through this module, so the artifact:
 
 - is written **atomically** (temp file + ``os.replace`` in the same

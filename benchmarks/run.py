@@ -20,7 +20,7 @@ def _fast() -> bool:
 
 def main() -> None:
     from benchmarks import fig2_delay, fig3_clusters, fig4_convergence, fig5_resource_usage
-    from benchmarks import fig6_approx, kernels_bench, obs_overhead, roofline_table
+    from benchmarks import fig6_approx, kernels_bench, roofline_table
     from benchmarks import resilience, scaling, serving, steptime
 
     t0 = time.time()
@@ -114,14 +114,6 @@ def main() -> None:
     claims = resilience.derived_claims(rows)
     all_rows += rows
     summary.append(("resilience", (time.time() - t) * 1e6 / max(len(rows), 1),
-                    ";".join(f"{k}={v:.2f}" for k, v in claims.items()), claims))
-
-    # --- observability: tracing overhead gate (DESIGN.md §10) ---
-    t = time.time()
-    rows = obs_overhead.run()
-    claims = obs_overhead.derived_claims(rows)
-    all_rows += rows
-    summary.append(("observability", (time.time() - t) * 1e6 / max(len(rows), 1),
                     ";".join(f"{k}={v:.2f}" for k, v in claims.items()), claims))
 
     # --- kernels: wire-path roofline + structural claims (DESIGN.md §12) ---

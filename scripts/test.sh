@@ -17,8 +17,6 @@ if [[ "${RUN_TIER2:-0}" == "1" ]]; then
   make churn-soak
   echo "== tier-2: coded-serving gate (BENCH_FAST=1 benchmarks/serving.py) =="
   make bench-serving
-  echo "== tier-2: observability overhead gate (BENCH_FAST=1 benchmarks/obs_overhead.py) =="
-  make bench-obs
   echo "== tier-2: chaos soak (mixed crash/hang/flaky/corrupt runs at m=10) =="
   make chaos-soak
   echo "== tier-2: resilience gate (BENCH_FAST=1 benchmarks/resilience.py) =="

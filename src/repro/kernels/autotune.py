@@ -24,9 +24,7 @@ that will run it, not by an assumption:
     no timing cost — interpret-mode wall clock is meaningless and the tests
     that sweep engines must not pay for a probe.
 
-All probes are cached per (question, shape) for the process lifetime;
-results land in the flight recorder when tracing is on (span name
-``autotune``), so a production trace shows what was picked and why.
+All probes are cached per (question, shape) for the process lifetime.
 """
 
 from __future__ import annotations
@@ -71,18 +69,6 @@ def interleaved_best_us(
     return best
 
 
-def _record(question: str, choice, timings: dict[str, float] | None) -> None:
-    try:  # tracing is optional; autotune must work without the obs layer
-        from repro.obs.trace import get_tracer
-
-        get_tracer().instant(
-            "autotune", question=question, choice=str(choice),
-            **({f"us_{k}": round(v, 1) for k, v in timings.items()} if timings else {}),
-        )
-    except Exception:
-        pass
-
-
 def best_tile_d(P: int, D: int) -> int | None:
     """Autotuned lane tile for ``coded_reduce_pallas`` at (P, D) — TPU only.
 
@@ -102,7 +88,6 @@ def best_tile_d(P: int, D: int) -> int | None:
              for t in cands}
         )
         choice = int(min(times, key=times.get))
-        _record(f"tile_d P={P} D={D}", choice, times)
         _CACHE[key] = choice
     return _CACHE[key]
 
@@ -145,7 +130,6 @@ def best_reduce_schedule(P: int, D: int) -> str:
             {n: functools.partial(f, w, g) for n, f in cands.items()}
         )
         choice = min(times, key=times.get)
-        _record(f"reduce_schedule P={P} D={D}", choice, times)
         _CACHE[key] = choice
     return _CACHE[key]
 
@@ -193,6 +177,5 @@ def wire_kernel_default(P: int = 8, D: int = 1 << 16) -> bool:
             "unfused": functools.partial(unfused, g, w, err),
         })
         choice = times["fused"] <= times["unfused"]
-        _record(f"wire_kernel P={P} D={D}", choice, times)
         _CACHE[key] = choice
     return _CACHE[key]

@@ -150,7 +150,7 @@ def _tpu_call_hints(n_d: int, flops: int, nbytes: int, interpret: bool) -> dict:
 
 
 @functools.partial(
-    jax.jit, static_argnames=("interpret", "tile_d", "out_dtype")
+    jax.jit, static_argnames=("interpret", "tile_d", "out_dtype", "name")
 )
 def coded_reduce_pallas(
     g: jnp.ndarray,
@@ -159,6 +159,7 @@ def coded_reduce_pallas(
     interpret: bool = False,
     tile_d: int | None = None,
     out_dtype: jnp.dtype | None = None,
+    name: str = "coded_reduce",
 ) -> jnp.ndarray:
     """g: (P, D) row stack; w: (P,) coefficients -> (D,) = Σ_p w[p]·g[p].
 
@@ -167,7 +168,8 @@ def coded_reduce_pallas(
     is always f32.  ``out_dtype`` defaults to ``g.dtype`` (pass f32 when
     reducing an int8 wire).  ``tile_d`` overrides the lane tile (autotuned on
     TPU via :func:`repro.kernels.autotune.best_tile_d`).  No padding copy is
-    made at any D (DESIGN.md §12).
+    made at any D (DESIGN.md §12).  ``name`` is the kernel's name in a
+    profiler trace.
     """
     P, D = g.shape
     td = int(tile_d) if tile_d else TILE_D
@@ -177,6 +179,7 @@ def coded_reduce_pallas(
 
     out = pl.pallas_call(
         functools.partial(_coded_reduce_kernel, n_p=n_p, rows_tail=rows_tail),
+        name=name,
         grid=(n_d, n_p),
         in_specs=[
             pl.BlockSpec((chunk, 1), lambda i, p: (p, 0)),

@@ -139,6 +139,7 @@ def flash_attention_pallas(
         }
     out = pl.pallas_call(
         kernel,
+        name="flash_attention",
         grid=(B * H, n_q, n_k),
         in_specs=[
             pl.BlockSpec((1, block_q, hd), lambda h, i, j: (h, i, 0)),

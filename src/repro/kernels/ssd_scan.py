@@ -99,6 +99,7 @@ def ssd_scan_pallas(
 
     y, hout = pl.pallas_call(
         kernel,
+        name="ssd_scan",
         grid=(B * H, nc),
         in_specs=[
             pl.BlockSpec((1, chunk, P), lambda h, c: (h, c, 0)),

@@ -164,6 +164,7 @@ def coded_encode_int8_pallas(
             _encode_kernel,
             n_d=n_d, n_p=n_p, rows_tail=rows_tail, d_total=D, tile_d=td,
         ),
+        name="coded_encode_int8",
         grid=(2, n_d, n_p),
         in_specs=[
             pl.BlockSpec((chunk, 1), lambda ph, i, p: (p, 0)),
@@ -206,5 +207,6 @@ def coded_decode_int8_pallas(
     from repro.kernels.coded_reduce import coded_reduce_pallas
 
     return coded_reduce_pallas(
-        q, ws, interpret=interpret, tile_d=tile_d, out_dtype=jnp.float32
+        q, ws, interpret=interpret, tile_d=tile_d, out_dtype=jnp.float32,
+        name="coded_decode_int8",
     )

@@ -11,6 +11,10 @@ and compile time flat in depth — essential for dry-running 72-layer models.
 
 Params are plain nested dicts; ``param_specs`` mirrors the structure with
 PartitionSpecs (TP over 'model', optional FSDP over 'data').
+
+Device work carries name scopes (DESIGN.md §10): ``embed``, ``layers`` (the
+scanned stack's body) and ``head_loss`` (final norm, logits and the
+cross-entropy).  Backward ops carry them under ``transpose(...)``.
 """
 
 from __future__ import annotations
@@ -201,12 +205,13 @@ class LM:
             bps = xs[0]
             cbs = xs[1] if len(xs) > 1 else (None,) * self.period
             new_caches = []
-            for j in range(self.period):
-                cj = cbs[j] if cbs[j] is not None and len(cbs[j]) else (cache_len if mode == "prefill" else None)
-                x = shard_batch(x)  # re-anchor DP sharding each block
-                x, a, nc = self._apply_block(j, bps[j], x, positions, cj, mode, pos_scalar)
-                aux = aux + a
-                new_caches.append(nc)
+            with jax.named_scope("layers"):
+                for j in range(self.period):
+                    cj = cbs[j] if cbs[j] is not None and len(cbs[j]) else (cache_len if mode == "prefill" else None)
+                    x = shard_batch(x)  # re-anchor DP sharding each block
+                    x, a, nc = self._apply_block(j, bps[j], x, positions, cj, mode, pos_scalar)
+                    aux = aux + a
+                    new_caches.append(nc)
             return (x, aux), tuple(new_caches)
 
         if cfg.remat == "full" and mode == "train":
@@ -223,12 +228,13 @@ class LM:
     def _embed(self, params: PyTree, batch: PyTree) -> tuple[jnp.ndarray, jnp.ndarray]:
         """Returns (x (B,S,d), label_mask_offset handled by caller)."""
         cfg = self.cfg
-        if cfg.frontend == "audio":
-            return batch["frames"].astype(_dtype(cfg))
-        tok = jnp.take(params["embed"], batch["tokens"], axis=0)
-        if cfg.frontend == "vision":
-            return jnp.concatenate([batch["patches"].astype(tok.dtype), tok], axis=1)
-        return tok
+        with jax.named_scope("embed"):
+            if cfg.frontend == "audio":
+                return batch["frames"].astype(_dtype(cfg))
+            tok = jnp.take(params["embed"], batch["tokens"], axis=0)
+            if cfg.frontend == "vision":
+                return jnp.concatenate([batch["patches"].astype(tok.dtype), tok], axis=1)
+            return tok
 
     def _logits(self, params: PyTree, x: jnp.ndarray) -> jnp.ndarray:
         head = params["lm_head"] if "lm_head" in params else params["embed"].T
@@ -243,8 +249,9 @@ class LM:
         x = self._embed(params, batch)
         positions = jnp.arange(x.shape[1], dtype=jnp.int32)
         x, aux, _ = self._run_stack(params, x, positions, "train")
-        x = rms_norm(x, params["final_norm"]["scale"], self.cfg.norm_eps)
-        return self._logits(params, x), aux
+        with jax.named_scope("head_loss"):
+            x = rms_norm(x, params["final_norm"]["scale"], self.cfg.norm_eps)
+            return self._logits(params, x), aux
 
     def seq_losses(self, params: PyTree, batch: PyTree) -> jnp.ndarray:
         """Per-sequence mean CE (+ per-seq MoE aux), shape (B,).
@@ -255,22 +262,25 @@ class LM:
         """
         cfg = self.cfg
         logits, aux = self.forward(params, batch)
-        labels = batch["labels"]
-        if cfg.frontend == "vision":
-            # patch positions carry no labels; text span starts at n_patches
-            logits = logits[:, cfg.n_patches :]
-        if not cfg.encoder_only:
-            logits, labels = logits[:, :-1], labels[:, 1:]
-        valid = labels >= 0
-        lab = jnp.where(valid, labels, 0)
-        logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
-        ll = jnp.take_along_axis(logp, lab[..., None], axis=-1)[..., 0]
-        ce = -jnp.sum(ll * valid, axis=-1) / jnp.maximum(jnp.sum(valid, axis=-1), 1)
-        return ce + cfg.aux_coef * aux
+        with jax.named_scope("head_loss"):
+            labels = batch["labels"]
+            if cfg.frontend == "vision":
+                # patch positions carry no labels; text span starts at n_patches
+                logits = logits[:, cfg.n_patches :]
+            if not cfg.encoder_only:
+                logits, labels = logits[:, :-1], labels[:, 1:]
+            valid = labels >= 0
+            lab = jnp.where(valid, labels, 0)
+            logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
+            ll = jnp.take_along_axis(logp, lab[..., None], axis=-1)[..., 0]
+            ce = -jnp.sum(ll * valid, axis=-1) / jnp.maximum(jnp.sum(valid, axis=-1), 1)
+            return ce + cfg.aux_coef * aux
 
     def weighted_loss(self, params: PyTree, batch: PyTree) -> jnp.ndarray:
         """Σ_b weight_b · seq_loss_b — the coded-DP training objective."""
-        return jnp.sum(self.seq_losses(params, batch) * batch["weight"])
+        losses = self.seq_losses(params, batch)
+        with jax.named_scope("head_loss"):
+            return jnp.sum(losses * batch["weight"])
 
     def prefill(self, params: PyTree, batch: PyTree, cache_len: int) -> tuple[jnp.ndarray, PyTree]:
         """Returns (last-position logits (B, V), cache)."""

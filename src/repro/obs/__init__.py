@@ -12,7 +12,7 @@
 
 from repro.obs.stats import Summary, pct
 from repro.obs.straggler import StragglerForensics, WorkerLedger
-from repro.obs.trace import NULL_TRACER, NullTracer, Tracer, get_tracer, set_tracer
+from repro.obs.trace import NULL_TRACER, NullTracer, Tracer
 
 __all__ = [
     "NULL_TRACER",
@@ -21,7 +21,5 @@ __all__ = [
     "Summary",
     "Tracer",
     "WorkerLedger",
-    "get_tracer",
     "pct",
-    "set_tracer",
 ]

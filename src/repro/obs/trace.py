@@ -22,13 +22,21 @@ export, so they never visually interleave):
   windows, worker arrivals, and request lifecycles live here: what the
   modelled cluster did.
 
-Zero-overhead-when-off contract: instrumented code holds a tracer
-reference that is either a real :class:`Tracer` (``enabled = True``) or
-the module-level :data:`NULL_TRACER` singleton.  Hot paths guard every
-emission with ``if tr.enabled:`` — tracing off therefore costs ONE
-attribute check per instrumented site, no allocation, no clock read
-(enforced by the ``observability`` overhead gate in BENCH_run.json).
-:class:`NullTracer` also no-ops every method, so cold paths may call it
+Spans reach the profiler.  ``Tracer.span()`` is the one way a wall-clock
+span is recorded: it enters ``jax.profiler.TraceAnnotation(name)`` (so the
+span lands, on the profiler's clock, in any device trace that is running)
+and records the same interval into the ring on ``perf_counter``.  The
+annotation is entered before the interval starts and left after it ends,
+so ring durations hold the host work alone.  ``span_at`` stays for the
+``sim`` clock and for spans whose endpoints are known only afterwards.
+
+Off-path rule: instrumented code holds a tracer reference that is either
+a real :class:`Tracer` (``enabled = True``) or the module-level
+:data:`NULL_TRACER` singleton.  A ``with tr.span(name):`` site costs one
+call when tracing is off, which returns the shared no-op span: no clock
+read, no annotation, no record.  Sites pass no arguments that would have
+to be built, and attach costly ones under ``if tr.enabled:``.  Every
+:class:`NullTracer` method is a no-op, so cold paths may call it
 unguarded.
 """
 
@@ -40,7 +48,9 @@ import time
 from collections import deque
 from typing import Any, Iterable, Iterator
 
-__all__ = ["NULL_TRACER", "NullTracer", "Tracer", "get_tracer", "set_tracer"]
+from jax import profiler
+
+__all__ = ["NULL_TRACER", "NullTracer", "Tracer"]
 
 # Chrome-export process ids per clock domain (pid 0 is reserved by some
 # viewers for the browser process; start at 1)
@@ -99,35 +109,30 @@ NULL_TRACER = NullTracer()
 
 
 class _Span:
-    """Context manager recording one wall-clock span on exit (and entering a
-    ``jax.profiler.TraceAnnotation`` when the tracer asks for device
-    alignment)."""
+    """Context manager for one wall-clock span: a profiler annotation
+    around the interval, and the interval recorded into the ring on exit."""
 
-    __slots__ = ("_tr", "_name", "_tid", "_args", "_t0", "_jax_ctx")
+    __slots__ = ("_tr", "_name", "_tid", "_args", "_t0", "_ann")
 
     def __init__(self, tr: "Tracer", name: str, tid: int, args: dict):
         self._tr = tr
         self._name = name
         self._tid = tid
         self._args = args
-        self._jax_ctx = None
 
     def set(self, **args) -> "_Span":
         self._args.update(args)
         return self
 
     def __enter__(self) -> "_Span":
-        ann = self._tr._annotation
-        if ann is not None:
-            self._jax_ctx = ann(self._name)
-            self._jax_ctx.__enter__()
+        self._ann = profiler.TraceAnnotation(self._name)
+        self._ann.__enter__()
         self._t0 = self._tr.clock()
         return self
 
     def __exit__(self, *exc) -> bool:
         t1 = self._tr.clock()
-        if self._jax_ctx is not None:
-            self._jax_ctx.__exit__(*exc)
+        self._ann.__exit__(*exc)
         self._tr.span_at(self._name, self._t0, t1, clock="wall", tid=self._tid, **self._args)
         return False
 
@@ -139,29 +144,17 @@ class Tracer:
       capacity: ring-buffer size in records; the oldest records are evicted
         (and counted in ``n_dropped``) once full — a long run keeps the
         most recent window, never unbounded memory.
-      jax_annotations: wrap wall-clock ``span()`` bodies in
-        ``jax.profiler.TraceAnnotation`` so a device profile captured with
-        ``jax.profiler.trace`` lines its XLA slices up with ours (no-op
-        when jax's profiler is unavailable).
     """
 
     enabled = True
 
-    def __init__(self, capacity: int = 1 << 16, *, jax_annotations: bool = False):
+    def __init__(self, capacity: int = 1 << 16):
         if capacity <= 0:
             raise ValueError("capacity must be positive")
         self._buf: deque[dict] = deque(maxlen=int(capacity))
         self._seq = 0
         self.n_dropped = 0
         self._epoch = time.perf_counter()
-        self._annotation = None
-        if jax_annotations:
-            try:
-                from jax.profiler import TraceAnnotation
-
-                self._annotation = TraceAnnotation
-            except Exception:  # profiler unavailable: wall spans still work
-                self._annotation = None
 
     # -- clocks --------------------------------------------------------------
 
@@ -179,8 +172,8 @@ class Tracer:
         self._buf.append(rec)
 
     def span(self, name: str, *, tid: int = 0, **args) -> _Span:
-        """Wall-clock span as a context manager (convenience path — hot
-        loops record via :meth:`span_at` behind an ``enabled`` guard)."""
+        """Wall-clock span as a context manager, annotated for the profiler
+        (the one way wall-clock spans are recorded)."""
         return _Span(self, name, tid, args)
 
     def span_at(
@@ -193,7 +186,9 @@ class Tracer:
         tid: int = 0,
         **args,
     ) -> None:
-        """Record a span with explicit endpoints in ``clock`` seconds."""
+        """Record a span with explicit endpoints in ``clock`` seconds (the
+        ``sim`` clock, or endpoints known only afterwards); it never
+        reaches the profiler."""
         self._record({
             "kind": "span", "name": name, "t0": float(t0), "t1": float(t1),
             "clock": clock, "tid": int(tid), "args": args,
@@ -341,20 +336,3 @@ def _jsonable(x: Any):
     if hasattr(x, "item"):
         return x.item()
     return str(x)
-
-
-# -- module-level default tracer (the one attribute hot paths check) ---------
-
-_TRACER: NullTracer | Tracer = NULL_TRACER
-
-
-def get_tracer() -> NullTracer | Tracer:
-    """The process-default tracer (``NULL_TRACER`` unless :func:`set_tracer`
-    installed a real one)."""
-    return _TRACER
-
-
-def set_tracer(tracer: Tracer | NullTracer | None) -> None:
-    """Install (or with None, remove) the process-default tracer."""
-    global _TRACER
-    _TRACER = tracer if tracer is not None else NULL_TRACER

@@ -285,27 +285,31 @@ class StepEngine:
         optimizer moments.  The guard is in-jit (no recompiles, no extra
         readback): grads are zeroed and the update reverted via selects, so
         the finite path is bit-identical to the unguarded step and the
-        caller detects the skip from the returned non-finite grad_norm."""
+        caller detects the skip from the returned non-finite grad_norm.
+        Its ops carry the ``adamw`` name scope (DESIGN.md §10)."""
         tc = self.tc
-        lr = self._lr(step)
-        gnorm = global_norm(grads)
-        ok = jnp.isfinite(gnorm)
-        grads = jax.tree.map(lambda g: jnp.where(ok, g, jnp.zeros_like(g)), grads)
-        new_params, new_opt = adamw_update(
-            params, grads, opt,
-            lr=lr, beta1=tc.beta1, beta2=tc.beta2, eps=tc.eps,
-            weight_decay=tc.weight_decay, grad_clip=tc.grad_clip,
-        )
-        new_params = jax.tree.map(lambda n, o: jnp.where(ok, n, o), new_params, params)
-        new_opt = jax.tree.map(lambda n, o: jnp.where(ok, n, o), new_opt, opt)
+        with jax.named_scope("adamw"):
+            lr = self._lr(step)
+            gnorm = global_norm(grads)
+            ok = jnp.isfinite(gnorm)
+            grads = jax.tree.map(lambda g: jnp.where(ok, g, jnp.zeros_like(g)), grads)
+            new_params, new_opt = adamw_update(
+                params, grads, opt,
+                lr=lr, beta1=tc.beta1, beta2=tc.beta2, eps=tc.eps,
+                weight_decay=tc.weight_decay, grad_clip=tc.grad_clip,
+            )
+            new_params = jax.tree.map(lambda n, o: jnp.where(ok, n, o), new_params, params)
+            new_opt = jax.tree.map(lambda n, o: jnp.where(ok, n, o), new_opt, opt)
         return new_params, new_opt, gnorm, lr
 
     def _device_batch(self, pbatch, a, support, pids, coeff, mask):
-        """In-jit pack + weights: the device-resident twin of _flat_batch."""
-        w = slot_weights_device(
-            jnp.asarray(a, jnp.float32), support, coeff, mask, pids, self.codec.k
-        )
-        return pack_flat_device(pbatch, pids, w)
+        """In-jit pack + weights: the device-resident twin of _flat_batch,
+        and the paper's encode (name scope ``coded_pack``, DESIGN.md §10)."""
+        with jax.named_scope("coded_pack"):
+            w = slot_weights_device(
+                jnp.asarray(a, jnp.float32), support, coeff, mask, pids, self.codec.k
+            )
+            return pack_flat_device(pbatch, pids, w)
 
     def _make_fused_step(self):
         def step_fn(params, opt, pbatch, a, support, pids, coeff, mask, step):
@@ -562,54 +566,48 @@ class StepEngine:
         # kernels ran inside it) / unravel, so obs_report's phase table shows
         # the encode+decode cost move when the fused wire path switches on
         tr = self.tracer
-        traced = tr.enabled
-        t0 = tr.clock() if traced else 0.0
-        if self._unravel is None:
-            self._unravel, width = wire_unraveler(params)
-            self._err_width = width if self.compress else 1
-        if self._err is None or self._err_version != self.codec.version:
-            # first call, or a membership change / rebalance re-encoded the
-            # plan: run the elastic rebuild — mesh + program re-derived at
-            # the live m, retained workers' error-feedback rows carried,
-            # joiners/leavers zeroed (DESIGN.md §13).  Must precede the
-            # pack: its jit closes over the plan's worker-axis shape.
-            self._rebuild_spmd()
-        if self._state_mesh_stale:
-            # params may still be committed to the pre-rebuild device set;
-            # the flag is cleared by step() once opt is re-placed too
-            params = self._replicate_on_mesh(params)
-        plan = self.codec.plan
-        pids, _, mask = self._device_plan()
-        pbatch = jax.tree.map(jnp.asarray, partition_batch)
-        sb = self._pack_slots(pbatch, pids.reshape(-1))
-        if support is None:
-            coeff = self._dev_coeff_mask  # cached, re-uploaded only on rebalance
-        else:
-            # unfinished partitions never left the worker: mask their slots
-            # out of the wire-format coded gradient g̃_w (on device — the
-            # (m, k) mask is the only per-step upload)
-            coeff = self._coeff_support(
-                self._dev_coeff_mask, pids, mask, self._support_dev(support)
-            )
-        a_dev = jnp.asarray(np.asarray(a) / plan.k, jnp.float32)
-        if traced:
-            t1 = tr.clock()
-            tr.span_at("phase.spmd.pack", t0, t1, clock="wall", where="host")
-        flat, self._err = self._spmd_grads(params, sb, coeff, a_dev, self._err)
-        if traced:
-            jax.block_until_ready(flat)
-            t2 = tr.clock()
-            kernels = (
-                "coded_encode_int8+all_gather(i8)+coded_decode_int8"
-                if self.wire_kernel
-                else "coded_reduce+psum(f32)"
-                + ("+quantize_int8" if self.compress else "")
-            )
-            tr.span_at("phase.spmd.grads", t1, t2, clock="wall", kernels=kernels)
-        out = self._unravel(flat)
-        if traced:
-            tr.span_at("phase.spmd.unravel", t2, tr.clock(), clock="wall")
-        return out
+        with tr.span("phase.spmd.pack"):
+            if self._unravel is None:
+                self._unravel, width = wire_unraveler(params)
+                self._err_width = width if self.compress else 1
+            if self._err is None or self._err_version != self.codec.version:
+                # first call, or a membership change / rebalance re-encoded
+                # the plan: run the elastic rebuild — mesh + program
+                # re-derived at the live m, retained workers' error-feedback
+                # rows carried, joiners/leavers zeroed (DESIGN.md §13).  Must
+                # precede the pack: its jit closes over the plan's
+                # worker-axis shape.
+                self._rebuild_spmd()
+            if self._state_mesh_stale:
+                # params may still be committed to the pre-rebuild device
+                # set; the flag is cleared by step() once opt is re-placed too
+                params = self._replicate_on_mesh(params)
+            plan = self.codec.plan
+            pids, _, mask = self._device_plan()
+            pbatch = jax.tree.map(jnp.asarray, partition_batch)
+            sb = self._pack_slots(pbatch, pids.reshape(-1))
+            if support is None:
+                coeff = self._dev_coeff_mask  # cached, re-uploaded only on rebalance
+            else:
+                # unfinished partitions never left the worker: mask their
+                # slots out of the wire-format coded gradient g̃_w (on device
+                # — the (m, k) mask is the only per-step upload)
+                coeff = self._coeff_support(
+                    self._dev_coeff_mask, pids, mask, self._support_dev(support)
+                )
+            a_dev = jnp.asarray(np.asarray(a) / plan.k, jnp.float32)
+        with tr.span("phase.spmd.grads") as sp:
+            flat, self._err = self._spmd_grads(params, sb, coeff, a_dev, self._err)
+            if tr.enabled:
+                jax.block_until_ready(flat)
+                sp.set(kernels=(
+                    "coded_encode_int8+all_gather(i8)+coded_decode_int8"
+                    if self.wire_kernel
+                    else "coded_reduce+psum(f32)"
+                    + ("+quantize_int8" if self.compress else "")
+                ))
+        with tr.span("phase.spmd.unravel"):
+            return self._unravel(flat)
 
     def gradients(self, params: PyTree, partition_batch: dict, a) -> PyTree:
         """Decoded gradient under decode vector ``a`` (ndarray, or a
@@ -648,82 +646,65 @@ class StepEngine:
         arrived, shapes unchanged, so the jitted path never recompiles).
 
         Phase spans (DESIGN.md §10): with tracing on, the host-side cost of
-        each step phase lands on the wall-clock track.  The fused backend is
-        ONE XLA program, so pack/encode/decode/apply collapse into a single
-        ``phase.fused`` span (its close includes the blocking metric
-        readback — i.e. device time); the protocol backends expose their
-        separable phases.  Tracing off costs one attribute check."""
+        each step phase is a span on the wall clock and in the profiler's
+        trace.  The fused backend is ONE XLA program: ``phase.upload`` (the
+        step's small inputs), ``phase.dispatch`` (the jitted call until it
+        returns) and ``phase.readback`` (the blocking metric reads, which
+        wait for the device); the protocol backends expose their separable
+        phases.  Tracing off costs one no-op call per phase."""
         tr = self.tracer
-        traced = tr.enabled
         a_vec, support = self._split_decode(a)
         if self.backend == "fused" and self.host_pack:
-            t0 = tr.clock() if traced else 0.0
-            batch = {
-                k: jnp.asarray(v)
-                for k, v in self._flat_batch(partition_batch, a_vec, support).items()
-            }
-            if traced:
-                t1 = tr.clock()
-                tr.span_at("phase.pack+upload", t0, t1, clock="wall", where="host")
-            params, opt, metrics = self._fused_step_host(
-                state.params, state.opt, batch, jnp.asarray(state.step)
-            )
-            out = {k: float(v) for k, v in metrics.items()}  # blocks on device
-            if traced:
-                tr.span_at("phase.fused", t1, tr.clock(), clock="wall",
-                           phases="fwd+bwd+decode+apply")
-        elif self.backend == "fused":
-            t0 = tr.clock() if traced else 0.0
-            pids, coeff, mask = self._device_plan()
-            pbatch = jax.tree.map(jnp.asarray, partition_batch)
-            a_dev = jnp.asarray(np.asarray(a_vec), jnp.float32)
-            sup_dev = self._support_dev(support)
-            if traced:
-                t1 = tr.clock()
-                tr.span_at("phase.upload", t0, t1, clock="wall",
-                           what="unique batch + decode vector + support mask")
-            params, opt, metrics = self._fused_step(
-                state.params, state.opt, pbatch, a_dev,
-                sup_dev, pids, coeff, mask, jnp.asarray(state.step),
-            )
-            out = {k: float(v) for k, v in metrics.items()}  # blocks on device
-            if traced:
-                tr.span_at("phase.fused", t1, tr.clock(), clock="wall",
-                           phases="pack+encode+decode+apply")
-        else:
-            t0 = tr.clock() if traced else 0.0
-            grads = self.gradients(state.params, partition_batch, a)
-            if self.backend == "spmd" and self._state_mesh_stale:
-                # a rebuild moved the mesh under this step: re-place the
-                # caller's (params, opt) onto it before the loss/apply jits
-                # mix them with new-mesh grads (device-to-device, values
-                # untouched — the resume stays bit-exact)
-                state = TrainerState(
-                    params=self._replicate_on_mesh(state.params),
-                    opt=self._replicate_on_mesh(state.opt),
-                    step=state.step,
+            with tr.span("phase.pack+upload"):
+                batch = {
+                    k: jnp.asarray(v)
+                    for k, v in self._flat_batch(partition_batch, a_vec, support).items()
+                }
+            with tr.span("phase.fused"):
+                params, opt, metrics = self._fused_step_host(
+                    state.params, state.opt, batch, jnp.asarray(state.step)
                 )
-                self._state_mesh_stale = False
-            if traced:
-                t1 = tr.clock()
-                name = ("phase.pack+encode+wire+decode" if self.backend == "spmd"
-                        else "phase.gradients")
-                tr.span_at(name, t0, t1, clock="wall", backend=self.backend)
-            pids, coeff, mask = self._device_plan()
-            pbatch = jax.tree.map(jnp.asarray, partition_batch)
-            loss = self._loss_fwd(
-                state.params, pbatch, jnp.asarray(np.asarray(a_vec), jnp.float32),
-                self._support_dev(support), pids, coeff, mask,
-            )
-            if traced:
-                t2 = tr.clock()
-                tr.span_at("phase.loss", t1, t2, clock="wall")
-            params, opt, metrics = self._apply(
-                state.params, state.opt, grads, jnp.asarray(state.step)
-            )
-            metrics = {**metrics, "loss": loss}
-            out = {k: float(v) for k, v in metrics.items()}  # blocks on device
-            if traced:
-                tr.span_at("phase.apply", t2, tr.clock(), clock="wall")
+                out = {k: float(v) for k, v in metrics.items()}  # blocks on device
+        elif self.backend == "fused":
+            with tr.span("phase.upload"):
+                pids, coeff, mask = self._device_plan()
+                pbatch = jax.tree.map(jnp.asarray, partition_batch)
+                a_dev = jnp.asarray(np.asarray(a_vec), jnp.float32)
+                sup_dev = self._support_dev(support)
+            with tr.span("phase.dispatch"):
+                params, opt, metrics = self._fused_step(
+                    state.params, state.opt, pbatch, a_dev,
+                    sup_dev, pids, coeff, mask, jnp.asarray(state.step),
+                )
+            with tr.span("phase.readback"):
+                out = {k: float(v) for k, v in metrics.items()}  # blocks on device
+        else:
+            with tr.span("phase.pack+encode+wire+decode" if self.backend == "spmd"
+                         else "phase.gradients"):
+                grads = self.gradients(state.params, partition_batch, a)
+                if self.backend == "spmd" and self._state_mesh_stale:
+                    # a rebuild moved the mesh under this step: re-place the
+                    # caller's (params, opt) onto it before the loss/apply
+                    # jits mix them with new-mesh grads (device-to-device,
+                    # values untouched — the resume stays bit-exact)
+                    state = TrainerState(
+                        params=self._replicate_on_mesh(state.params),
+                        opt=self._replicate_on_mesh(state.opt),
+                        step=state.step,
+                    )
+                    self._state_mesh_stale = False
+            with tr.span("phase.loss"):
+                pids, coeff, mask = self._device_plan()
+                pbatch = jax.tree.map(jnp.asarray, partition_batch)
+                loss = self._loss_fwd(
+                    state.params, pbatch, jnp.asarray(np.asarray(a_vec), jnp.float32),
+                    self._support_dev(support), pids, coeff, mask,
+                )
+            with tr.span("phase.apply"):
+                params, opt, metrics = self._apply(
+                    state.params, state.opt, grads, jnp.asarray(state.step)
+                )
+                metrics = {**metrics, "loss": loss}
+                out = {k: float(v) for k, v in metrics.items()}  # blocks on device
         new_state = TrainerState(params=params, opt=opt, step=state.step + 1)
         return new_state, out

@@ -35,9 +35,6 @@ from repro.obs.trace import NULL_TRACER, Tracer
 
 __all__ = ["DevicePrefetcher"]
 
-_DONE = object()  # worker sentinel: range exhausted
-
-
 class _Poison:
     """Worker-thread failure shipped to the consumer for re-raising."""
 
@@ -60,10 +57,11 @@ class DevicePrefetcher:
     semaphore the consumer releases as it takes each batch).
 
     With a tracer installed (DESIGN.md §10), each background
-    generate+upload lands as a ``prefetch.upload`` span on its own
-    wall-clock track (tid=1) — overlap with the ``step`` spans on tid=0 is
-    the double-buffering working as designed; a gap before a step span is
-    a prefetch stall.
+    generate+upload is a ``prefetch.upload`` span on its own wall-clock
+    track (tid=1) — overlap with the ``step`` spans on tid=0 is the
+    double-buffering working as designed — and the consumer's wait for its
+    next batch is a ``prefetch.wait`` span (tid=0): a long one is a
+    prefetch stall.
     """
 
     def __init__(
@@ -77,16 +75,12 @@ class DevicePrefetcher:
         self.tracer = trace if trace is not None else NULL_TRACER
 
     def _load(self, step: int):
-        tr = self.tracer
-        t0 = tr.clock() if tr.enabled else 0.0
-        batch = self.data.batch(step)
-        out = (
-            jax.device_put(batch, self.device) if self.device is not None
-            else jax.device_put(batch)
-        )
-        if tr.enabled:
-            tr.span_at("prefetch.upload", t0, tr.clock(), clock="wall", tid=1, step=step)
-        return out
+        with self.tracer.span("prefetch.upload", tid=1):
+            batch = self.data.batch(step)
+            return (
+                jax.device_put(batch, self.device) if self.device is not None
+                else jax.device_put(batch)
+            )
 
     def _worker(self, q: queue.Queue, slots: threading.Semaphore,
                 stop_ev: threading.Event) -> None:
@@ -100,7 +94,6 @@ class DevicePrefetcher:
                 if stop_ev.is_set():
                     return
                 q.put((step, self._load(step)))
-            q.put(_DONE)
         except BaseException as exc:  # noqa: BLE001 - shipped to the consumer
             q.put(_Poison(exc, sys.exc_info()[2]))
 
@@ -115,11 +108,13 @@ class DevicePrefetcher:
             name="prefetch", daemon=True,
         )
         worker.start()
+        tr = self.tracer
         try:
-            while True:
-                item = q.get()
-                if item is _DONE:
-                    return
+            # exactly one item per step: the consumer never waits for an
+            # end marker, so every ``prefetch.wait`` precedes a step
+            for _ in range(self.start, self.stop):
+                with tr.span("prefetch.wait"):
+                    item = q.get()
                 slots.release()  # the previous batch slot is free again
                 if isinstance(item, _Poison):
                     # surface the worker's failure as the ORIGINAL exception

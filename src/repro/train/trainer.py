@@ -128,8 +128,9 @@ class CodedTrainer:
         self.elastic.on_transition = self.engine.note_membership
         # -- observability (DESIGN.md §10): one tracer threaded through the
         # whole stack.  Off (the default) it is the NULL singleton and every
-        # instrumented site costs one attribute check; the numerics are
-        # identical either way (tested bit-equal in tests/test_obs.py).
+        # instrumented site costs one no-op call or one attribute check; the
+        # numerics are identical either way (tested bit-equal in
+        # tests/test_obs.py).
         self.tracer = trace if trace is not None else NULL_TRACER
         self.engine.tracer = self.tracer
         self.elastic.tracer = self.tracer
@@ -385,10 +386,20 @@ class CodedTrainer:
         """One arrival-driven BSP step — exact or deadline semantics are
         the policy's choice, not a separate code path.  Scheduled join/leave
         events for this step are applied FIRST, so the new worker set's
-        clocks, decode, and gradients all see the transition."""
+        clocks, decode, and gradients all see the transition.  With tracing
+        on, the whole step is the ``step`` span (DESIGN.md §10)."""
+        with self.tracer.span("step") as sp:
+            new_state, out = self._step(state, partition_batch, profile)
+            if self.tracer.enabled:
+                sp.set(step=int(state.step), skipped=bool(out["skipped"]))
+        return new_state, out
+
+    def _step(
+        self, state: TrainerState, partition_batch: dict[str, np.ndarray],
+        profile: StragglerProfile | None,
+    ) -> tuple[TrainerState, dict[str, float]]:
         tr = self.tracer
-        traced = tr.enabled  # ONE attribute check when tracing is off
-        t_step0 = tr.clock() if traced else 0.0
+        traced = tr.enabled
         sup = self.supervisor
         if sup is not None:
             # the fault layer perturbs clocks per training step; pending
@@ -429,11 +440,9 @@ class CodedTrainer:
             )
 
         # --- timing model + decode resolution (what the paper measures) ---
-        t0 = tr.clock() if traced else 0.0
-        tick = self.elastic.tick(profile)
+        with tr.span("step.resolve"):
+            tick = self.elastic.tick(profile)
         if traced:
-            tr.span_at("step.resolve", t0, tr.clock(), clock="wall",
-                       step=int(state.step))
             loads_now = self.elastic.codec.code.worker_load().astype(np.float64)
         outcome = tick.outcome
         corrupt_cur: tuple[int, ...] = ()
@@ -484,7 +493,7 @@ class CodedTrainer:
                 "exact_fraction": self._exact_fraction(),
             }
             if traced:
-                self._record_step(state.step, tick, loads_now, out, t_step0)
+                self._record_step(state.step, tick, loads_now, out)
             return state, out
 
         new_state, metrics = self._guarded_step(
@@ -492,11 +501,8 @@ class CodedTrainer:
         )
 
         # --- throughput estimation + elastic re-encode ---
-        t0 = tr.clock() if traced else 0.0
-        self.elastic.observe(tick)
-        if traced:
-            tr.span_at("step.observe", t0, tr.clock(), clock="wall",
-                       step=int(state.step))
+        with tr.span("step.observe"):
+            self.elastic.observe(tick)
         out = {
             **metrics, **base,
             "n_used": float(tick.n_used),
@@ -506,12 +512,11 @@ class CodedTrainer:
         if self.elastic.maybe_rebalance(new_state.step, every=self.coding.rebalance_every):
             out["rebalanced"] = 1.0
         if traced:
-            self._record_step(state.step, tick, loads_now, out, t_step0)
+            self._record_step(state.step, tick, loads_now, out)
         return new_state, out
 
     def _record_step(
         self, step: int, tick, loads: np.ndarray, out: dict[str, float],
-        t_wall0: float,
     ) -> None:
         """Tracing-only per-step emission (DESIGN.md §10): the sim-clock
         iteration window + per-worker arrival instants, the forensics
@@ -580,8 +585,6 @@ class CodedTrainer:
             c_est=np.asarray(self.elastic.estimator.c, np.float64).tolist(),
             c_true=np.asarray(self.elastic.true_speeds, np.float64).tolist(),
         )
-        tr.span_at("step", t_wall0, tr.clock(), clock="wall", step=int(step),
-                   skipped=skipped)
 
     # -- checkpoint extras ---------------------------------------------------
 

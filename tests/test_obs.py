@@ -11,6 +11,7 @@ verbatim.  Tracing OFF must leave numerics bit-equal and record nothing.
 
 import json
 import math
+import threading
 
 import jax
 import jax.numpy as jnp
@@ -191,7 +192,8 @@ def test_trace_spans_nest_and_markers_present(traced_run):
     spans = tracer.records("span")
     names = {r["name"] for r in tracer.records()}
     # step phases on the wall clock
-    for phase in ("step", "step.resolve", "phase.upload", "phase.fused"):
+    for phase in ("step", "step.resolve", "phase.upload", "phase.dispatch",
+                  "phase.readback"):
         assert phase in names, f"missing {phase}"
     # the guaranteed markers
     assert "elastic.rebalance" in names
@@ -272,6 +274,105 @@ def test_tracing_off_records_nothing_and_is_bit_equal():
     for a, b in zip(jax.tree.leaves(s_off.params), jax.tree.leaves(s_on.params)):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
     assert len(t_on.tracer) > 0  # the traced twin actually recorded
+
+
+# ---------------------------------------------------------------------------
+# spans reach the profiler: every wall span is a TraceAnnotation
+# ---------------------------------------------------------------------------
+
+
+class _Annotations:
+    """Stands in for ``jax.profiler.TraceAnnotation``: logs (thread, event,
+    name) for every annotation entered and left."""
+
+    def __init__(self):
+        self.log = []
+        self._lock = threading.Lock()
+
+    def __call__(self, name, **kw):
+        rec = self
+
+        class _Ann:
+            def __enter__(self):
+                with rec._lock:
+                    rec.log.append((threading.get_ident(), "enter", name))
+
+            def __exit__(self, *exc):
+                with rec._lock:
+                    rec.log.append((threading.get_ident(), "exit", name))
+
+        return _Ann()
+
+
+class _Batches:
+    def __init__(self, pb):
+        self.pb = pb
+
+    def batch(self, step):
+        return self.pb
+
+
+WALL_SPANS = ("step", "step.resolve", "step.observe", "phase.upload",
+              "phase.dispatch", "phase.readback", "prefetch.wait")
+
+
+def _annotated_run(monkeypatch, trace):
+    ann = _Annotations()
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", ann)
+    tr, pb = _mk(trace)
+    tr.run(tr.init_state(jax.random.PRNGKey(0)), _Batches(pb), 2)
+    return ann.log
+
+
+def test_spans_enter_profiler_annotations_nested_per_thread(monkeypatch):
+    log = _annotated_run(monkeypatch, Tracer())
+    main = threading.get_ident()
+    entered = [(t, n) for t, ev, n in log if ev == "enter"]
+    for name in WALL_SPANS:
+        assert entered.count((main, name)) == 2, name
+    uploads = [t for t, n in entered if n == "prefetch.upload"]
+    assert len(uploads) == 2 and main not in uploads
+    stacks = {}
+    for t, ev, name in log:  # every annotation closes in LIFO order on its thread
+        st = stacks.setdefault(t, [])
+        if ev == "enter":
+            st.append(name)
+        else:
+            assert st and st.pop() == name
+    assert all(not st for st in stacks.values())
+    steps = [n for t, ev, n in log if t == main and ev == "enter"]
+    # the step's phases run inside it; the wait for the next batch outside
+    depth, inside = 0, set()
+    for t, ev, name in log:
+        if t != main:
+            continue
+        if name == "step":
+            depth += 1 if ev == "enter" else -1
+        elif ev == "enter" and depth:
+            inside.add(name)
+    assert inside == set(WALL_SPANS) - {"step", "prefetch.wait"}
+    assert steps[0] == "prefetch.wait"
+
+
+def test_tracing_off_enters_no_annotation(monkeypatch):
+    log = _annotated_run(monkeypatch, None)
+    assert log == []
+
+
+def test_ring_span_records_the_annotated_interval(monkeypatch):
+    ann = _Annotations()
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", ann)
+    tracer = Tracer()
+    with tracer.span("outer", tid=2, k=1) as sp:
+        sp.set(j=2)
+        with tracer.span("inner"):
+            pass
+    assert [(ev, n) for _, ev, n in ann.log] == [
+        ("enter", "outer"), ("enter", "inner"), ("exit", "inner"), ("exit", "outer")]
+    inner, outer = tracer.records("span")
+    assert outer["clock"] == "wall" and outer["tid"] == 2
+    assert outer["args"] == {"k": 1, "j": 2}
+    assert outer["t0"] <= inner["t0"] <= inner["t1"] <= outer["t1"]
 
 
 # ---------------------------------------------------------------------------
@@ -362,13 +463,15 @@ def test_obs_report_end_to_end(traced_run, tmp_path, capsys):
     obs_report.main([str(path), "--top-k", "3"])
     out = capsys.readouterr().out
     assert "span breakdown" in out
-    assert "phase.fused" in out and "sim.iteration" in out
+    assert "phase.dispatch" in out and "phase.readback" in out
+    assert "sim.iteration" in out
     assert "straggler forensics" in out
     assert "top blame" in out
     # aggregation helpers agree with the raw records
     records = obs_report.load_records(str(path))
     rows = obs_report.phase_table(records)
-    fused = next(r for r in rows if r["phase"] == "phase.fused")
-    assert fused["n"] == 8 and fused["clock"] == "wall"
+    for name in ("phase.dispatch", "phase.readback"):
+        row = next(r for r in rows if r["phase"] == name)
+        assert row["n"] == 8 and row["clock"] == "wall"
     rep = obs_report.blame_report(records, top_k=2)
     assert rep["summary"]["steps"] > 0 and len(rep["blame"]) <= 2
