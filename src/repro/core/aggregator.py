@@ -7,7 +7,7 @@ Three implementations of the same math, used at different layers:
    real deployment puts on the wire), the master decodes g = Σ_w a_w·g̃_w.
    Oracle for tests and the convergence benchmarks.  O(m·n) backward passes.
 
-2. ``fused_coded_value_and_grad`` — the production path.  Linear encoding
+2. ``fused_coded_value_and_grad`` — the fused form.  Linear encoding
    commutes with ∇, so worker w's coded gradient is ∇_θ Σ_j B[w,j]·L(D_j),
    ONE backward pass over a weighted loss; folding the decode coefficient
    a_w in as well, the ordinary data-parallel gradient psum that XLA inserts
@@ -30,10 +30,12 @@ Three implementations of the same math, used at different layers:
    compression-enabled path.
 
 The device-resident data-path contract (DESIGN.md §6) lives here too:
-``slot_weights_device`` / ``pack_flat_device`` are the in-jit twins of the
-host ``slot_weights`` / ``_flat_batch`` pack, consuming the small per-step
-device inputs (decode vector ``a`` (m,), ``support`` (m,k)) plus the
-plan tensors that the engine keeps device-resident between rebalances.
+``slot_weights_device`` is the in-jit twin of the host ``slot_weights``;
+``unique_batch_device`` sums those slot weights per partition and hands the
+fused step the k·mb unique sequences, where the host ``_flat_batch`` oracle
+replicates them over every slot.  Both consume the small per-step device
+inputs (decode vector ``a`` (m,), ``support`` (m,k)) plus the plan tensors
+that the engine keeps device-resident between rebalances.
 
 Deployment note (see DESIGN.md §3): within one SPMD program all chips step in
 lock-step, so the (s+1)× compute redundancy buys gradient *exactness when
@@ -67,8 +69,8 @@ __all__ = [
     "slot_weights_device",
     "support_slot_mask",
     "support_slot_mask_device",
+    "unique_batch_device",
     "pack_coded_batch",
-    "pack_flat_device",
     "protocol_reference",
     "fused_coded_value_and_grad",
     "faithful_spmd_step",
@@ -266,27 +268,32 @@ def slot_weights_device(
     return w.astype(jnp.float32)
 
 
-def pack_flat_device(
-    partition_batch: dict, slot_pids: jnp.ndarray, weights: jnp.ndarray
+def unique_batch_device(
+    partition_batch: dict, slot_pids: jnp.ndarray, weights: jnp.ndarray, k: int
 ) -> dict:
-    """In-jit slot pack: partition-major leaves (k, mb, ...) -> the fused
-    flat coded batch (m·n_slots·mb, ...) with per-sequence loss weights.
+    """In-jit encode: partition-major leaves (k, mb, ...) -> the k·mb unique
+    sequences (k·mb, ...) with per-sequence loss weights c_j/mb.
 
-    The (s+1)×-replicated coded working set is materialized HERE, on device,
-    by an XLA gather — the host only ever ships the k·mb unique sequences
-    (DESIGN.md §6).  ``weights`` is the (m, n_slots) output of
-    :func:`slot_weights_device`.
+    By linearity Σ_{w,s} W[w,s]·L_{pid(w,s)} = Σ_j c_j·L_j, so one
+    forward/backward over the unique batch gives the decoded gradient of
+    the (s+1)×-replicated slot batch — exactly, for exact, inexact and
+    partial-work decodes, wherever the loss is per sequence.  Inside one
+    program the replicas move in lock step and tolerate nothing, so the
+    fused step computes each partition once (DESIGN.md §3, §6).  No gather:
+    the leaves are reshaped.  ``weights`` is the (m, n_slots) output of
+    :func:`slot_weights_device`; padding and unfinished slots carry 0.
     """
-    idx = slot_pids.reshape(-1)  # (m*n_slots,)
+    # c_j = Σ_{slots holding j} W[w,s] as an f32 segment-sum (a scatter-add),
+    # never a one-hot matmul, whose TPU default precision is bf16: the
+    # decode's cancellations happen here, before any bf16 op sees the weight
+    c = jax.ops.segment_sum(
+        weights.reshape(-1).astype(jnp.float32), slot_pids.reshape(-1), num_segments=k
+    )
     out = {}
-    mb = None
     for key, x in partition_batch.items():
-        # gather on a 2-D (k, mb·rest) view — XLA lowers row gathers of flat
-        # rows to straight memcpys, several× faster than an N-D take
-        g = jnp.take(x.reshape((x.shape[0], -1)), idx, axis=0)
         mb = x.shape[1]
-        out[key] = g.reshape((-1,) + x.shape[2:])
-    out["weight"] = (jnp.repeat(weights.reshape(-1), mb) / mb).astype(jnp.float32)
+        out[key] = x.reshape((k * mb,) + x.shape[2:])  # raises unless x holds k partitions
+    out["weight"] = jnp.repeat(c, mb) / mb
     return out
 
 

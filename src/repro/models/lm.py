@@ -141,7 +141,8 @@ class LM:
         self, j: int, bp: PyTree, x: jnp.ndarray, positions: jnp.ndarray,
         cache: PyTree | None, mode: str, pos_scalar: jnp.ndarray | None,
     ) -> tuple[jnp.ndarray, jnp.ndarray, PyTree]:
-        """Returns (x, aux_loss, new_cache)."""
+        """Returns (x, aux_loss, new_cache); a MoE layer's aux is per
+        sequence, shape (B,), every other layer's the scalar zero."""
         cfg = self.cfg
         spec = self.plan[j]
         aux = jnp.zeros((), jnp.float32)
@@ -187,7 +188,7 @@ class LM:
                         capacity_factor=cfg.capacity_factor, act=cfg.act,
                     )
                 )(h)
-                aux = aux + jnp.mean(a)
+                aux = aux + a  # per sequence: the coded loss stays Σ_b w_b·loss_b
             else:
                 y = mlp(bp["mlp"], h, cfg.act)
             x = x + y
@@ -218,7 +219,10 @@ class LM:
             body = jax.checkpoint(body, prevent_cse=False)
 
         xs = (params["blocks"],) if caches is None else (params["blocks"], caches)
-        (x, aux), new_caches = jax.lax.scan(body, (x, jnp.zeros((), jnp.float32)), xs)
+        # a stack with a MoE layer carries its load-balance loss per sequence
+        has_moe = any(spec.mlp == "moe" for spec in self.plan)
+        aux0 = jnp.zeros((x.shape[0],) if has_moe else (), jnp.float32)
+        (x, aux), new_caches = jax.lax.scan(body, (x, aux0), xs)
         return x, aux, (new_caches if mode in ("prefill", "decode") else None)
 
     # ------------------------------------------------------------------
@@ -231,7 +235,11 @@ class LM:
         with jax.named_scope("embed"):
             if cfg.frontend == "audio":
                 return batch["frames"].astype(_dtype(cfg))
-            tok = jnp.take(params["embed"], batch["tokens"], axis=0)
+            # taken from an f32 view, the lookup's gradient (a scatter-add over
+            # the batch's tokens) sums a token's repeats in f32: the TPU's bf16
+            # scatter-add loses most of a token that repeats hundreds of times
+            table = params["embed"]
+            tok = jnp.take(table.astype(jnp.float32), batch["tokens"], axis=0).astype(table.dtype)
             if cfg.frontend == "vision":
                 return jnp.concatenate([batch["patches"].astype(tok.dtype), tok], axis=1)
             return tok
@@ -245,7 +253,9 @@ class LM:
     # ------------------------------------------------------------------
 
     def forward(self, params: PyTree, batch: PyTree) -> tuple[jnp.ndarray, jnp.ndarray]:
-        """Full forward.  Returns (logits (B, S_total, V), aux_loss)."""
+        """Full forward.  Returns (logits (B, S_total, V), aux_loss): the
+        MoE load-balance loss per sequence (B,), or the scalar zero for a
+        stack with no MoE layer."""
         x = self._embed(params, batch)
         positions = jnp.arange(x.shape[1], dtype=jnp.int32)
         x, aux, _ = self._run_stack(params, x, positions, "train")

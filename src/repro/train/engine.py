@@ -5,10 +5,11 @@ gradient backends (DESIGN.md §3), on a fully device-resident data path
   - ``fused``     — production path.  Encode/decode folded into per-sequence
                     loss weights; ONE jitted fwd/bwd + AdamW with donated
                     buffers; XLA's DP reduction *is* the decode.  The slot
-                    pack (partition-major (k, mb, ...) -> (s+1)×-replicated
-                    flat coded batch) and the slot weights are computed
-                    INSIDE the jit from small per-step device inputs, so the
-                    host only ships the k·mb unique sequences per step.
+                    weights are computed INSIDE the jit from small per-step
+                    device inputs and summed per partition, and forward/
+                    backward run over the k·mb unique sequences only: inside
+                    one program the (s+1)× replicas would move in lock step
+                    and tolerate nothing, so no slot is replicated.
   - ``reference`` — the paper's protocol verbatim (O(m·n) backward passes,
                     python loops).  Oracle for tests/debugging; applies the
                     same AdamW update so whole-run comparisons work.
@@ -31,8 +32,10 @@ Device residency contract: the plan tensors (``slot_pids`` / ``slot_coeff``
 every value-changing path (elastic rebalance, checkpoint restore) rebuilds
 the plan, so the next step re-uploads — nothing else ever re-materializes
 them.  ``host_pack=True`` preserves the
-pre-§6 host-side numpy pack (oracle for equivalence tests and the
-``benchmarks/steptime.py`` before/after comparison).
+pre-§6 host-side numpy pack over the replicated slot batch (oracle for
+equivalence tests and the ``benchmarks/steptime.py`` before/after
+comparison).  Replication lives only there and in the ``spmd`` backend,
+whose workers are separate programs that each compute their slots.
 """
 
 from __future__ import annotations
@@ -50,11 +53,11 @@ from repro.configs.base import TrainConfig
 from repro.core.aggregator import (
     faithful_spmd_step,
     pack_coded_batch,
-    pack_flat_device,
     protocol_reference,
     slot_weights,
     slot_weights_device,
     support_slot_mask_device,
+    unique_batch_device,
     wire_unraveler,
 )
 from repro.core.codec import Codec
@@ -253,10 +256,11 @@ class StepEngine:
         self, partition_batch: dict[str, np.ndarray], a: np.ndarray,
         support: np.ndarray | None = None,
     ) -> dict:
-        """HOST-side pack oracle: partition-major (k, mb, ...) -> flat coded
-        batch (m·n_slots·mb, ...) with decode/encode folded into per-seq
-        weights.  The pre-§6 data path — kept as the ``host_pack=True``
-        baseline the device pack is property-tested against."""
+        """HOST-side pack oracle: partition-major (k, mb, ...) -> replicated
+        flat coded batch (m·n_slots·mb, ...) with decode/encode folded into
+        per-seq weights.  The pre-§6 data path — kept as the
+        ``host_pack=True`` baseline the unique-batch encode is tested
+        against."""
         plan = self.codec.plan
         idx = plan.slot_pids.reshape(-1)  # (m*n_slots,)
         out = {}
@@ -303,13 +307,14 @@ class StepEngine:
         return new_params, new_opt, gnorm, lr
 
     def _device_batch(self, pbatch, a, support, pids, coeff, mask):
-        """In-jit pack + weights: the device-resident twin of _flat_batch,
-        and the paper's encode (name scope ``coded_pack``, DESIGN.md §10)."""
+        """In-jit weights over the unique batch: the paper's encode (name
+        scope ``coded_pack``, DESIGN.md §10).  Same decoded gradient as the
+        replicated _flat_batch, one pass per partition."""
         with jax.named_scope("coded_pack"):
             w = slot_weights_device(
                 jnp.asarray(a, jnp.float32), support, coeff, mask, pids, self.codec.k
             )
-            return pack_flat_device(pbatch, pids, w)
+            return unique_batch_device(pbatch, pids, w, self.codec.k)
 
     def _make_fused_step(self):
         def step_fn(params, opt, pbatch, a, support, pids, coeff, mask, step):
@@ -338,8 +343,8 @@ class StepEngine:
         return grads_fn
 
     def _make_packed_loss(self):
-        """Weighted loss at the decoded slot weights, packed in-jit (the
-        metric the non-fused backends report)."""
+        """Weighted loss over the unique batch at the decoded weights,
+        encoded in-jit (the metric the non-fused backends report)."""
 
         def loss_fn(params, pbatch, a, support, pids, coeff, mask):
             batch = self._device_batch(pbatch, a, support, pids, coeff, mask)
@@ -649,9 +654,11 @@ class StepEngine:
         each step phase is a span on the wall clock and in the profiler's
         trace.  The fused backend is ONE XLA program: ``phase.upload`` (the
         step's small inputs), ``phase.dispatch`` (the jitted call until it
-        returns) and ``phase.readback`` (the blocking metric reads, which
-        wait for the device); the protocol backends expose their separable
-        phases.  Tracing off costs one no-op call per phase."""
+        returns; it carries ``rows``, the k·mb sequences through forward/
+        backward, and ``slot_rows``, the m·n_slots·mb the code assigns) and
+        ``phase.readback`` (the blocking metric reads, which wait for the
+        device); the protocol backends expose their separable phases.
+        Tracing off costs one no-op call per phase."""
         tr = self.tracer
         a_vec, support = self._split_decode(a)
         if self.backend == "fused" and self.host_pack:
@@ -671,11 +678,15 @@ class StepEngine:
                 pbatch = jax.tree.map(jnp.asarray, partition_batch)
                 a_dev = jnp.asarray(np.asarray(a_vec), jnp.float32)
                 sup_dev = self._support_dev(support)
-            with tr.span("phase.dispatch"):
+            with tr.span("phase.dispatch") as sp:
                 params, opt, metrics = self._fused_step(
                     state.params, state.opt, pbatch, a_dev,
                     sup_dev, pids, coeff, mask, jnp.asarray(state.step),
                 )
+                if tr.enabled:
+                    # sequences through forward/backward vs what the code assigns
+                    k, mb = jax.tree.leaves(pbatch)[0].shape[:2]
+                    sp.set(rows=int(k * mb), slot_rows=int(pids.size * mb))
             with tr.span("phase.readback"):
                 out = {k: float(v) for k, v in metrics.items()}  # blocks on device
         else:

@@ -1,11 +1,12 @@
 """Double-buffered host→device batch prefetch (DESIGN.md §6).
 
 The partition-major batch is the ONLY bulk host→device transfer the
-device-resident step loop makes (k·mb unique sequences — the (s+1)×
-replication happens on device).  ``DevicePrefetcher`` overlaps even that:
-batch t+1 is materialized (host numpy) AND uploaded (``jax.device_put``)
-on a background thread while the consumer runs step t, so the step never
-waits on batch generation or the wire.  Host batch builders are numpy-bound
+device-resident step loop makes (k·mb unique sequences — the fused step
+computes only those; the spmd backend replicates them on device).
+``DevicePrefetcher`` overlaps even that: batch t+1 is materialized (host
+numpy) AND uploaded (``jax.device_put``) on a background thread while the
+consumer runs step t, so the step never waits on batch generation or the
+wire.  Host batch builders are numpy-bound
 and the jitted step blocks in XLA — both release the GIL, so the overlap
 is real even in-process.
 

@@ -1,15 +1,18 @@
 """Device-resident data path (DESIGN.md §6) equivalence suite.
 
-The contract under test: the in-jit slot pack + slot weights (device path)
-and the flat-gradient Pallas decode produce EXACTLY what the pre-§6 host
-numpy pack / per-leaf tree decode produced — across every registered
-scheme, exact and inexact decodes (DecodeOutcome with support masks), on
-the backends runnable in-process (fused device/host + reference; the spmd
-leg runs on a real mesh in tests/spmd_driver.py).  Also: the engine's
+The contract under test: the in-jit encode over the unique batch (device
+path) and the flat-gradient Pallas decode produce what the pre-§6 host
+numpy pack over the replicated slots / per-leaf tree decode produced —
+across every registered scheme, exact and inexact decodes (DecodeOutcome
+with support masks), on the backends runnable in-process (fused
+device/host + reference; the spmd leg runs on a real mesh in
+tests/spmd_driver.py).  Also: the engine's
 device-resident plan cache invalidates on rebalance, and the trainer's
 double-buffered prefetch loop is step-for-step identical to the manual
 loop.
 """
+
+import dataclasses
 
 import jax
 import jax.numpy as jnp
@@ -18,7 +21,7 @@ import pytest
 
 from repro.configs.base import CodingConfig, TrainConfig
 from repro.core import Codec, get_scheme, scheme_names
-from repro.core.aggregator import pack_flat_device, slot_weights_device
+from repro.core.aggregator import slot_weights_device, unique_batch_device
 from repro.train.engine import StepEngine
 
 _C4 = [1.0, 2.0, 3.0, 2.0]
@@ -61,18 +64,36 @@ def _tree_close(ta, tb, atol=3e-5, rtol=3e-4):
 # ---------------------------------------------------------------------------
 
 
+class _RowsModel(_ToyModel):
+    """_ToyModel that records the rows of every batch it is traced on."""
+
+    def __init__(self):
+        self.rows = []
+
+    def weighted_loss(self, params, batch):
+        self.rows.append(batch["x"].shape[0])
+        return super().weighted_loss(params, batch)
+
+
 @pytest.mark.parametrize("name", sorted(scheme_names()))
 def test_device_pack_matches_host_flat_batch(name):
-    """The in-jit gather/weights reproduce the host numpy pack bit-for-bit
-    (same f32 formula) for exact AND partial-work decodes."""
+    """The in-jit encode hands the model the k·mb unique sequences (the
+    partition-major batch reshaped), each partition weighted by the sum of
+    the host replicated pack's weights over that partition's slots — for
+    exact AND partial-work decodes."""
     codec = _codec(name)
-    eng = StepEngine(_ToyModel(), TrainConfig(), codec, backend="fused", host_pack=True)
+    model = _RowsModel()
+    eng = StepEngine(model, TrainConfig(), codec, backend="fused")
+    host_eng = StepEngine(model, TrainConfig(), codec, backend="fused", host_pack=True)
     pb = _partition_batch(codec.k)
+    mb = pb["x"].shape[1]
     rng = np.random.default_rng(3)
     outcome = codec.decode_outcome(range(codec.m))
+    a = outcome.a
     support = (rng.uniform(size=(codec.m, codec.k)) < 0.7).astype(np.float64)
-    for a, sup in [(outcome.a, None), (outcome.a, support)]:
-        host = eng._flat_batch(pb, a, sup)
+    slot_pids = np.repeat(codec.plan.slot_pids.reshape(-1), mb)  # pid of each host row
+    for sup in [None, support]:
+        host = host_eng._flat_batch(pb, a, sup)
         pids = jnp.asarray(codec.plan.slot_pids)
         sup_dev = (
             jnp.ones((codec.m, codec.k), jnp.float32) if sup is None
@@ -83,13 +104,21 @@ def test_device_pack_matches_host_flat_batch(name):
             jnp.asarray(codec.plan.slot_coeff), jnp.asarray(codec.plan.slot_mask),
             pids, codec.k,
         )
-        dev = pack_flat_device({k: jnp.asarray(v) for k, v in pb.items()}, pids, w)
+        dev = unique_batch_device({k: jnp.asarray(v) for k, v in pb.items()}, pids, w, codec.k)
         assert set(dev) == set(host)
-        for key in host:
-            np.testing.assert_allclose(
-                np.asarray(dev[key]), host[key], atol=1e-7, rtol=1e-6,
+        for key in pb:
+            np.testing.assert_array_equal(
+                np.asarray(dev[key]), pb[key].reshape((codec.k * mb,) + pb[key].shape[2:]),
                 err_msg=f"{name}/{key}",
             )
+        host_c = np.array([host["weight"][slot_pids == j].sum() for j in range(codec.k)])
+        np.testing.assert_allclose(
+            np.asarray(dev["weight"]).reshape(codec.k, mb).sum(axis=1), host_c,
+            atol=1e-7, rtol=1e-6, err_msg=f"{name}/weight",
+        )
+        eng.gradients(model.init(jax.random.PRNGKey(0)), pb, dataclasses.replace(outcome, support=sup))
+    # traced once (the shapes do not depend on the decode): k·mb rows, not m·n_slots·mb
+    assert model.rows == [codec.k * mb], name
 
 
 @pytest.mark.parametrize("name", sorted(scheme_names()))
@@ -134,6 +163,130 @@ def test_device_gradients_match_on_inexact_outcomes(name, seed):
     g_ref = StepEngine(model, tc, codec, backend="reference").gradients(params, pb, outcome)
     _tree_close(g_dev, g_host, atol=1e-6, rtol=1e-5)
     _tree_close(g_dev, g_ref)
+
+
+def test_dispatch_span_counts_unique_and_slot_rows():
+    """With tracing on, the fused step's ``phase.dispatch`` span says how many
+    sequences ran forward/backward (k·mb) and how many the code assigns
+    (m·n_slots·mb)."""
+    from repro.obs.trace import Tracer
+
+    codec = _codec("heter_aware")
+    eng = StepEngine(_ToyModel(), TrainConfig(), codec, backend="fused")
+    eng.tracer = Tracer()
+    pb = _partition_batch(codec.k)
+    eng.step(eng.init_state(jax.random.PRNGKey(0)), pb, codec.decode_vector(range(codec.m)))
+    (rec,) = eng.tracer.records(kind="span", name="phase.dispatch")
+    mb = pb["x"].shape[1]
+    assert rec["args"] == {"rows": codec.k * mb, "slot_rows": codec.m * codec.n_slots * mb}
+
+
+# ---------------------------------------------------------------------------
+# language models: MoE aux per sequence, bf16 under an ill-conditioned decode
+# ---------------------------------------------------------------------------
+
+
+def _lm(arch, **overrides):
+    from repro.configs import get_config
+    from repro.models.lm import build_model
+
+    cfg = dataclasses.replace(get_config(arch).reduced(), **overrides)
+    return cfg, build_model(cfg)
+
+
+def _lm_batch(cfg, k, mb=2, seq=16, step=0):
+    from repro.data.pipeline import SyntheticData
+
+    return SyntheticData(cfg, k=k, part_mb=mb, seq_len=seq).batch(step)
+
+
+@pytest.mark.parametrize("inexact", [False, True])
+def test_moe_unique_pass_matches_protocol(inexact):
+    """A MoE stack's load-balance loss is per sequence, so the fused pass over
+    the unique batch is the paper protocol's decoded gradient (capacity
+    high enough that no token drops), exact and partial-work decodes."""
+    cfg, model = _lm("mixtral-8x7b", n_layers=2)
+    assert cfg.capacity_factor >= 8.0 and cfg.aux_coef > 0
+    codec = _codec("heter_aware", seed=1)
+    params = model.init(jax.random.PRNGKey(3))
+    pb = _lm_batch(cfg, codec.k, mb=1)
+    if inexact:
+        support = (np.random.default_rng(5).uniform(size=(codec.m, codec.k)) < 0.6).astype(np.float64)
+        outcome = codec.decode_partial(support)
+    else:
+        outcome = codec.decode_outcome([0, 2, 3])
+    tc = TrainConfig()
+    g_fused = StepEngine(model, tc, codec, backend="fused").gradients(params, pb, outcome)
+    g_ref = StepEngine(model, tc, codec, backend="reference").gradients(params, pb, outcome)
+    _tree_close(g_fused, g_ref)
+
+
+def test_moe_aux_per_sequence_keeps_uniform_loss():
+    """Under uniform weights the per-sequence aux gives the batch-mean form's
+    loss, mean(ce) + aux_coef·mean(aux); a stack with no MoE layer keeps the
+    scalar zero aux."""
+    cfg, model = _lm("mixtral-8x7b", n_layers=2)
+    _, ce_only = _lm("mixtral-8x7b", n_layers=2, aux_coef=0.0)
+    params = model.init(jax.random.PRNGKey(4))
+    pb = _lm_batch(cfg, k=4)
+    batch = {key: jnp.asarray(v.reshape((-1,) + v.shape[2:])) for key, v in pb.items()}
+    n = batch["tokens"].shape[0]
+    batch["weight"] = jnp.full((n,), 1.0 / n, jnp.float32)
+    _, aux = jax.jit(model.forward)(params, batch)
+    assert aux.shape == (n,)
+    assert float(jnp.std(aux)) > 0  # the sequences route differently
+    ce = jax.jit(ce_only.seq_losses)(params, batch)
+    want = jnp.mean(ce) + cfg.aux_coef * jnp.mean(aux)
+    got = jax.jit(model.weighted_loss)(params, batch)
+    assert float(got) == pytest.approx(float(want), rel=1e-6)
+
+    _, dense = _lm("smollm-360m", n_layers=2)
+    _, dense_aux = jax.eval_shape(dense.forward, dense.init(jax.random.PRNGKey(4)), batch)
+    assert dense_aux.shape == ()
+
+
+def _amplification(codec, a):
+    """max_j Σ_w|a_w·B_wj| / |Σ_w a_w·B_wj|: how far the decode cancels."""
+    aB = np.asarray(a, np.float64)[:, None] * codec.scheme.B
+    return float(np.max(np.abs(aB).sum(0) / np.abs(aB.sum(0))))
+
+
+def _rel_err(tree, ref):
+    diff = sum(float(jnp.sum((x.astype(jnp.float32) - y) ** 2))
+               for x, y in zip(jax.tree.leaves(tree), jax.tree.leaves(ref)))
+    norm = sum(float(jnp.sum(y ** 2)) for y in jax.tree.leaves(ref))
+    return (diff / norm) ** 0.5
+
+
+def test_bf16_fused_grad_does_not_grow_with_decode_amplification():
+    """bf16 model, heter_aware codes drawn from two seeds, one worker faulted:
+    a decode that cancels by over 1000× loses no more precision in the fused
+    step than one that cancels by under 20, because the slot weights are
+    summed per partition in f32 before any bf16 op; the replicated host pack
+    weights each slot's bf16 gradient separately and does lose it."""
+    cfg, model = _lm("smollm-360m", n_layers=2, dtype="bfloat16")
+    _, model32 = _lm("smollm-360m", n_layers=2, dtype="float32")
+    params = model.init(jax.random.PRNGKey(0))
+    p32 = jax.tree.map(lambda x: x.astype(jnp.float32), params)
+    k = 8
+    pb = _lm_batch(cfg, k, mb=1)
+    flat = {key: jnp.asarray(v.reshape((-1,) + v.shape[2:])) for key, v in pb.items()}
+    flat["weight"] = jnp.full((k,), 1.0 / k, jnp.float32)
+    g_ref = jax.grad(model32.weighted_loss)(p32, flat)  # the exact decode's mean gradient
+    errs, amps = {}, {}
+    tc = TrainConfig()
+    for label, seed, fault in (("low", 60, 1), ("high", 170, 1)):
+        codec = Codec(get_scheme("heter_aware", m=4, k=k, s=1, c=[2.0, 4.0, 8.0, 16.0], rng=seed))
+        outcome = codec.decode_outcome([w for w in range(codec.m) if w != fault])
+        assert outcome.exact
+        amps[label] = _amplification(codec, outcome.a)
+        eng = StepEngine(model, tc, codec, backend="fused")
+        errs[label] = _rel_err(eng.gradients(params, pb, outcome), g_ref)
+    host = StepEngine(model, tc, codec, backend="fused", host_pack=True)
+    errs["high, replicated"] = _rel_err(host.gradients(params, pb, outcome), g_ref)
+    assert amps["low"] < 20 and amps["high"] > 1000, amps
+    assert errs["high"] <= 2 * errs["low"], errs
+    assert errs["high, replicated"] > 2 * errs["low"], errs
 
 
 # ---------------------------------------------------------------------------
