@@ -83,7 +83,11 @@ def ssd_chunked(
     chunk: int,
     h0: jnp.ndarray | None = None,  # (B, H, P, N) initial state
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """Chunked SSD.  Returns (y (B,S,H,P), final state (B,H,P,N))."""
+    """Chunked SSD.  Returns (y (B,S,H,P), final state (B,H,P,N)).
+
+    Head h reads group h // R of B and C (R = rep = H // G).  The heads are
+    viewed as (G, R), so C·Bᵀ is built once per group and B, C are never
+    copied to every head; only the decay is per head."""
     B, S, H, P = x.shape
     G, N = Bm.shape[2], Bm.shape[3]
     assert S % chunk == 0, f"seq {S} not divisible by chunk {chunk}"
@@ -91,50 +95,49 @@ def ssd_chunked(
     nc = S // chunk
     f32 = jnp.float32
 
-    xc = x.reshape(B, nc, chunk, H, P).astype(f32)
-    ac = dA.reshape(B, nc, chunk, H).transpose(0, 3, 1, 2).astype(f32)  # (B,H,nc,L)
+    xc = x.reshape(B, nc, chunk, G, rep, P).astype(f32)
+    ac = dA.reshape(B, nc, chunk, G, rep).transpose(0, 3, 4, 1, 2).astype(f32)  # (B,G,R,nc,L)
     Bc = Bm.reshape(B, nc, chunk, G, N).astype(f32)
     Cc = Cm.reshape(B, nc, chunk, G, N).astype(f32)
-    # broadcast groups to heads
-    Bh = jnp.repeat(Bc, rep, axis=3)  # (B,nc,L,H,N)
-    Ch = jnp.repeat(Cc, rep, axis=3)
+    # (B,G,R,nc,L) -> (B,nc,L,G,R,1): a per-head factor along x's layout
+    along_x = lambda t: t.transpose(0, 3, 4, 1, 2)[..., None]
 
-    a_cum = jnp.cumsum(ac, axis=-1)  # (B,H,nc,L)
-    Lmat = jnp.exp(_segsum(ac))  # (B,H,nc,L,L)
+    a_cum = jnp.cumsum(ac, axis=-1)  # (B,G,R,nc,L)
+    Lmat = jnp.exp(_segsum(ac))  # (B,G,R,nc,L,L)
 
     # 1. intra-chunk (diagonal blocks): attention-like dense matmuls
-    CB = jnp.einsum("bclhn,bcshn->bhcls", Ch, Bh)
-    y_diag = jnp.einsum("bhcls,bhcls,bcshp->bclhp", CB, Lmat, xc)
+    CB = jnp.einsum("bclgn,bcsgn->bgcls", Cc, Bc)  # (B,G,nc,L,L): once per group
+    y_diag = jnp.einsum("bgrcls,bcsgrp->bclgrp", CB[:, :, None] * Lmat, xc)
 
     # 2. per-chunk final states
-    decay_states = jnp.exp(a_cum[..., -1:] - a_cum)  # (B,H,nc,L)
-    states = jnp.einsum("bclhn,bhcl,bclhp->bchpn", Bh, decay_states, xc)
+    decay_states = jnp.exp(a_cum[..., -1:] - a_cum)  # (B,G,R,nc,L)
+    states = jnp.einsum("bclgn,bclgrp->bcgrpn", Bc, xc * along_x(decay_states))
 
     # 3. inter-chunk recurrence (prefix over nc chunk states)
-    chunk_decay = jnp.exp(a_cum[..., -1])  # (B,H,nc)
+    chunk_decay = jnp.exp(a_cum[..., -1])  # (B,G,R,nc)
 
     def chunk_step(h, inp):
-        st, dec = inp  # (B,H,P,N), (B,H)
+        st, dec = inp  # (B,G,R,P,N), (B,G,R)
         h_out = h  # state entering this chunk
         h = h * dec[..., None, None] + st
         return h, h_out
 
     h_init = (
-        jnp.zeros((B, H, P, N), f32)
+        jnp.zeros((B, G, rep, P, N), f32)
         if h0 is None
-        else h0.astype(f32)
+        else h0.reshape(B, G, rep, P, N).astype(f32)
     )
-    states_t = states.transpose(1, 0, 2, 3, 4)  # (nc,B,H,P,N)
-    decay_t = chunk_decay.transpose(2, 0, 1)  # (nc,B,H)
+    states_t = states.transpose(1, 0, 2, 3, 4, 5)  # (nc,B,G,R,P,N)
+    decay_t = chunk_decay.transpose(3, 0, 1, 2)  # (nc,B,G,R)
     h_final, h_in = jax.lax.scan(chunk_step, h_init, (states_t, decay_t))
-    h_in = h_in.transpose(1, 0, 2, 3, 4)  # (B,nc,H,P,N): state entering chunk c
+    h_in = h_in.transpose(1, 0, 2, 3, 4, 5)  # (B,nc,G,R,P,N): state entering chunk c
 
     # 4. off-diagonal contribution from carried state
-    state_decay = jnp.exp(a_cum)  # (B,H,nc,L)
-    y_off = jnp.einsum("bclhn,bchpn,bhcl->bclhp", Ch, h_in, state_decay)
+    state_decay = jnp.exp(a_cum)  # (B,G,R,nc,L)
+    y_off = jnp.einsum("bclgn,bcgrpn->bclgrp", Cc, h_in) * along_x(state_decay)
 
     y = (y_diag + y_off).reshape(B, S, H, P)
-    return y, h_final
+    return y, h_final.reshape(B, H, P, N)
 
 
 def ssd_sequential(x, dA, Bm, Cm, h0=None):
@@ -202,13 +205,16 @@ def mamba_forward(
     Cm = Cm.reshape(B, S, n_groups, d_state)
     dt = jax.nn.softplus(dt.astype(jnp.float32) + params["dt_bias"])  # (B,S,H)
     A = -jnp.exp(params["A_log"])  # (H,)
-    pad = (-S) % chunk  # zero-pad to a chunk multiple: x=0 adds nothing to the
-    if pad:  # state and dA=0 gives decay exp(0)=1, so padding is exact
-        zp = lambda t: jnp.pad(t, [(0, pad if i == 1 else 0) for i in range(t.ndim)])
-        y, h = ssd_chunked(zp(xs * dt[..., None]), zp(dt * A), zp(Bm), zp(Cm), chunk)
-        y = y[:, :S]
-    else:
-        y, h = ssd_chunked(xs * dt[..., None], dt * A, Bm, Cm, chunk)
+    # zero-pad to a chunk multiple: x=0 adds nothing to the state and dA=0
+    # gives decay exp(0)=1, so padding is exact
+    pad = (-S) % chunk
+    with jax.named_scope("ssd"):
+        if pad:
+            zp = lambda t: jnp.pad(t, [(0, pad if i == 1 else 0) for i in range(t.ndim)])
+            y, h = ssd_chunked(zp(xs * dt[..., None]), zp(dt * A), zp(Bm), zp(Cm), chunk)
+            y = y[:, :S]
+        else:
+            y, h = ssd_chunked(xs * dt[..., None], dt * A, Bm, Cm, chunk)
     y = y + params["D"][None, None, :, None] * xs.astype(jnp.float32)
     y = y.reshape(B, S, d_inner).astype(x.dtype)
     # gated RMSNorm (mamba2): norm(y * silu(z))

@@ -124,3 +124,82 @@ def test_synthetic_data_determinism():
     # partition function is addressable: partition j == batch slice j
     p2 = d1.partition(3, 2)
     np.testing.assert_array_equal(p2["tokens"], b1["tokens"][2])
+
+
+# ---------------------------------------------------------------------------
+# SSD math: the chunked form against the sequential scan
+# ---------------------------------------------------------------------------
+
+
+def _ssd_inputs(H, G, seed, B=2, S=48, P=8, N=16):
+    r = np.random.default_rng(seed)
+    x = jnp.asarray(r.normal(size=(B, S, H, P)), jnp.float32)
+    dA = -jnp.asarray(r.uniform(0.01, 0.3, size=(B, S, H)), jnp.float32)
+    Bm = jnp.asarray(r.normal(size=(B, S, G, N)), jnp.float32)
+    Cm = jnp.asarray(r.normal(size=(B, S, G, N)), jnp.float32)
+    h0 = jnp.asarray(r.normal(size=(B, H, P, N)), jnp.float32)
+    wy = jnp.asarray(r.normal(size=(B, S, H, P)), jnp.float32)
+    wh = jnp.asarray(r.normal(size=(B, H, P, N)), jnp.float32)
+    return (x, dA, Bm, Cm, h0), (wy, wh)
+
+
+def _rel(a, b):
+    return float(jnp.linalg.norm(a - b) / jnp.linalg.norm(b))
+
+
+@pytest.mark.parametrize("with_h0", [False, True], ids=["h0_zero", "h0_given"])
+@pytest.mark.parametrize("heads_groups", [(4, 1), (4, 2), (4, 4)], ids=lambda hg: f"H{hg[0]}G{hg[1]}")
+def test_ssd_chunked_matches_sequential(heads_groups, with_h0):
+    """Output, final state and the gradients for x, dA, B, C and h0 of the
+    chunked SSD, whose groups serve rep = H // G heads each, equal the
+    O(S) scan that gives every head its own copy of B and C."""
+    from repro.models.ssm import ssd_chunked, ssd_sequential
+
+    H, G = heads_groups
+    args, (wy, wh) = _ssd_inputs(H, G, seed=10 * H + G + with_h0)
+    if not with_h0:
+        args = args[:4] + (None,)
+    argnums = (0, 1, 2, 3, 4) if with_h0 else (0, 1, 2, 3)
+
+    def loss(ssd):
+        def f(x, dA, Bm, Cm, h0):
+            y, h = ssd(x, dA, Bm, Cm, h0)
+            return jnp.sum(y * wy) + jnp.sum(h * wh), (y, h)
+        return jax.grad(f, argnums=argnums, has_aux=True)
+
+    with jax.default_matmul_precision("highest"):
+        g_ch, (y_ch, h_ch) = loss(lambda *a: ssd_chunked(*a[:4], 16, a[4]))(*args)
+        g_sq, (y_sq, h_sq) = loss(ssd_sequential)(*args)
+    assert _rel(y_ch, y_sq) < 1e-5
+    assert _rel(h_ch, h_sq) < 1e-5
+    for name, a, b in zip(("x", "dA", "B", "C", "h0"), g_ch, g_sq):
+        assert a.shape == b.shape
+        assert _rel(a, b) < 1e-5, name
+
+
+@pytest.mark.parametrize("n_groups", [1, 2])
+def test_mamba_forward_pads_to_chunk(n_groups):
+    """A sequence that is not a chunk multiple: the zero-padded prefill
+    gives the outputs and the cache that one-token decode builds step by
+    step, and the input gradient of an unpadded chunking."""
+    from repro.models.ssm import init_mamba, mamba_decode, mamba_forward
+
+    dims = dict(d_inner=32, n_heads=4, d_state=8, n_groups=n_groups)
+    params = init_mamba(jax.random.PRNGKey(3), 16, **dims)
+    B, S = 2, 20  # chunk 16 pads to 32
+    x = jnp.asarray(np.random.default_rng(4).normal(size=(B, S, 16)), jnp.float32)
+    decode = jax.jit(lambda x, cache: mamba_decode(params, x, cache, **dims))
+    grad = jax.jit(jax.grad(lambda x, c: jnp.sum(jnp.sin(mamba_forward(params, x, chunk=c, **dims)[0]))),
+                   static_argnums=1)
+    with jax.default_matmul_precision("highest"):
+        out, cache = mamba_forward(params, x, chunk=16, return_cache=True, **dims)
+        step = {"h": jnp.zeros_like(cache["h"]), "conv": jnp.zeros_like(cache["conv"])}
+        outs = []
+        for t in range(S):
+            o, step = decode(x[:, t : t + 1], step)
+            outs.append(o)
+        g16, g5 = grad(x, 16), grad(x, 5)
+    assert _rel(out, jnp.concatenate(outs, axis=1)) < 1e-5
+    assert _rel(cache["h"], step["h"]) < 1e-5
+    np.testing.assert_array_equal(np.asarray(cache["conv"]), np.asarray(step["conv"]))
+    assert _rel(g16, g5) < 1e-5
